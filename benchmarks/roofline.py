@@ -1,8 +1,10 @@
 """Roofline terms per (arch x shape x mesh) from the dry-run artifacts.
 
-  compute    = HLO_FLOPs / (chips * 197e12)          [bf16 peak, v5e]
-  memory     = HLO_bytes / (chips * 819e9)
-  collective = ICI_bytes/chip / 50e9  +  DCN_bytes/chip / 6.25e9
+  compute    = HLO_FLOPs / peak bf16 FLOP/s
+  memory     = HLO_bytes / peak HBM bytes/s
+  collective = ICI_bytes/chip / ICI link bytes/s + DCN_bytes/chip / DCN bytes/s
+
+with the peaks of the record's `device_kind` from `PEAKS`.
 
 HLO_FLOPs / HLO_bytes are the loop-aware totals from repro.analysis.hlo
 (XLA's cost_analysis visits while bodies once; we verified the raw numbers
@@ -23,7 +25,27 @@ from typing import Dict, Optional
 
 from repro.configs.base import ALL_SHAPES
 from repro.configs.registry import get_config
-from repro.launch.mesh import DCN_BW, HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+
+# Per-chip peaks, keyed by `jax.Device.device_kind`. A kind missing here is
+# an error, never a default. Source for "TPU v5 lite" (TPU v5e): Google
+# Cloud documentation, "TPU v5e" -- 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB
+# of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links of
+# 50 GB/s). The DCN figure per host pair is a planning assumption, not a
+# published peak.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9,
+                    "ici_bw": 50e9, "dcn_bw": 6.25e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table row of one device kind; raises for unknown kinds."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak figures for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
 
 DRYRUN = Path(__file__).resolve().parent / "dryrun_results"
 RESULTS = Path(__file__).resolve().parent / "results"
@@ -145,16 +167,18 @@ def roofline_terms(rec: dict) -> dict:
     terms divide by per-chip peaks only. MODEL_FLOPS is global and divides
     by the chip count."""
     chips = rec["devices"]
-    compute_s = rec["hlo_flops"] / PEAK_FLOPS_BF16
+    pk = peaks(rec["device_kind"])
+    compute_s = rec["hlo_flops"] / pk["bf16_flops"]
     # memory term uses the kernel-adjusted traffic (innermost loop bodies =
     # one fused Pallas kernel); the raw post-CPU-fusion number is reported
     # alongside as memory_s_xla
-    memory_s = rec.get("hlo_bytes_kernel_adj", rec["hlo_bytes"]) / HBM_BW
-    memory_s_xla = rec["hlo_bytes"] / HBM_BW
+    memory_s = (rec.get("hlo_bytes_kernel_adj", rec["hlo_bytes"])
+                / pk["hbm_bw"])
+    memory_s_xla = rec["hlo_bytes"] / pk["hbm_bw"]
     ici_bytes = (rec["collective_bytes_total"]
                  - rec.get("collective_bytes_dcn", 0.0))
-    coll_s = ici_bytes / ICI_BW \
-        + rec.get("collective_bytes_dcn", 0.0) / DCN_BW
+    coll_s = ici_bytes / pk["ici_bw"] \
+        + rec.get("collective_bytes_dcn", 0.0) / pk["dcn_bw"]
     mf = model_flops(rec["arch"], rec["shape"])
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "memory_s_xla": memory_s_xla,
@@ -165,7 +189,7 @@ def roofline_terms(rec: dict) -> dict:
               key=lambda k: terms[k])
     terms["bottleneck"] = dom.replace("_s", "")
     step = max(compute_s, memory_s, coll_s)
-    terms["roofline_fraction"] = (mf / (chips * PEAK_FLOPS_BF16)) / step \
+    terms["roofline_fraction"] = (mf / (chips * pk["bf16_flops"])) / step \
         if step > 0 else 0.0
     return terms
 

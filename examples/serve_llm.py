@@ -51,14 +51,8 @@ def main():
         # runs inside each replica actor's constructor: pre-compile every
         # (wave width, prompt length) shape the trace can produce, so no
         # cold jit blows deadlines once the open-loop clock starts
-        import numpy as np
-        from repro.serving import Request
         eng = ServingEngine(model, params, max_seq=max_seq)
-        for plen in serving_load.LENGTH_BUCKETS:
-            for width in range(1, max_batch + 1):
-                reqs = [Request(0, np.arange(plen, dtype=np.int32) % 7 + 1,
-                                max_new_tokens=2) for _ in range(width)]
-                eng.serve(reqs, max_wave=width)
+        eng.warm(serving_load.LENGTH_BUCKETS, max_batch)
         return eng
 
     # fixed fleet: the example demonstrates the open-loop SLO path;

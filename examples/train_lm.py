@@ -56,7 +56,8 @@ def build_step_fns(model, opt_cfg):
     return grad_shard, reduce_grads, apply_update
 
 
-def main():
+def main(argv=None):
+    """Train and print the loss curve; returns [(step, loss)]."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--steps", type=int, default=40)
@@ -70,7 +71,7 @@ def main():
                     help="use the full (not reduced) architecture config")
     ap.add_argument("--sync", action="store_true",
                     help="single-process jit loop (no task runtime)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = (get_config(args.arch) if args.full
            else get_smoke_config(args.arch).scaled(
@@ -158,8 +159,9 @@ def main():
     first, last = losses[0][1], losses[-1][1]
     print(f"loss {first:.3f} -> {last:.3f} "
           f"({'improved' if last < first else 'NOT improved'})")
-    return 0 if last < first else 1
+    return losses
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    curve = main()
+    raise SystemExit(0 if curve[-1][1] < curve[0][1] else 1)

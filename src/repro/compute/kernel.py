@@ -6,8 +6,8 @@ only on nodes declaring that capacity and the node's dedicated device
 lane executes it. The wrapper:
 
 - jit-compiles the function once (unless it is already jitted or
-  ``jit=False``) — the Pallas ops wrappers in `repro.kernels` pick
-  interpret mode off-TPU themselves, so the same task runs in CI;
+  ``jit=False``) — the Pallas ops wrappers in `repro.kernels` compile on
+  TPU and interpret on the CPU themselves, so the same task runs in CI;
 - optionally warms the compile cache at *registration* time
   (``warmup_args=``), so the first cluster dispatch measures dispatch,
   not tracing;
@@ -16,9 +16,8 @@ lane executes it. The wrapper:
   on-device milliseconds, which `profiler.summarize` folds into
   ``kernel_tasks`` / ``kernel_time_ms_mean``.
 
-Thread backend only for the lane pinning; under the process backend the
-resource ledger alone serializes device tasks (and the function must be
-module-level for spawn safety, like any process-backend task).
+Device tasks run on the thread backend only: a chip belongs to one
+process, so `core.init(backend="process")` refuses device capacity.
 """
 from __future__ import annotations
 
@@ -26,23 +25,19 @@ import functools
 import time
 from typing import Any, Dict, Optional, Tuple
 
+import jax
+
 from repro.core.api import RemoteFunction
 from repro.core.worker import current_node, current_task
-
-try:  # the jax_pallas image bakes jax in; stay importable without it
-    import jax
-except ImportError:  # pragma: no cover
-    jax = None
 
 
 def _block(out: Any) -> Any:
     """Wait for async device execution so the measured window covers the
-    kernel, not just its dispatch. No-op for plain numpy results."""
-    if jax is not None:
-        try:
-            return jax.block_until_ready(out)
-        except Exception:  # non-jax leaves (e.g. python scalars)
-            return out
+    kernel, not just its dispatch. Leaves that are not jax arrays (numpy
+    results, python scalars) have nothing to wait for; a device error
+    raises here."""
+    jax.block_until_ready([x for x in jax.tree.leaves(out)
+                           if isinstance(x, jax.Array)])
     return out
 
 
@@ -76,7 +71,7 @@ class KernelFunction(RemoteFunction):
                  max_retries: int = -1, retry_exceptions=None,
                  backoff: float = 0.0, deadline: float = 0.0):
         self.kernel_fn = fn
-        if jit and jax is not None and not hasattr(fn, "lower"):
+        if jit and not hasattr(fn, "lower"):
             fn = jax.jit(fn, static_argnames=static_argnames)
         self._compiled = fn
         super().__init__(_instrument(fn, getattr(fn, "__name__",

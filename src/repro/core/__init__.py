@@ -22,7 +22,7 @@ from repro.core.memory import (MemoryManager,  # noqa: F401
 from repro.core.object_store import (ObjectStore,  # noqa: F401
                                      SharedMemoryStore, SpawnSafetyError)
 from repro.core.devices import (DEVICE_RESOURCE_KEYS,  # noqa: F401
-                                device_keys)
+                                ProcessBackendDeviceError, device_keys)
 from repro.core.runtime import (Cluster, DeviceLane,  # noqa: F401
                                 FailureDetector, Node)
 from repro.core.worker import (ActorContext, GetTimeoutError,  # noqa: F401

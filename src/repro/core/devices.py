@@ -7,8 +7,8 @@ capacity a node physically holds — so the scheduler, the dispatch path,
 and the compute plane agree on which requests are hard placement
 constraints with a dedicated executor lane behind them.
 
-Pure-constant leaf module: imported by the scheduler, the runtime, and
-the compute package, so it must not import any of them.
+Leaf module: imported by the scheduler, the runtime, and the compute
+package, so it must not import any of them.
 """
 from typing import Dict, Tuple
 
@@ -28,3 +28,18 @@ def device_keys(resources: Dict[str, float]) -> Tuple[str, ...]:
 
 def device_subset(resources: Dict[str, float]) -> Dict[str, float]:
     return {k: resources[k] for k in device_keys(resources)}
+
+
+class ProcessBackendDeviceError(ValueError):
+    """A process-backend node declared device capacity. A chip belongs to
+    one process at a time: a spawned worker that opened it would fail or
+    hang while the driver holds it. Device nodes run the thread backend."""
+
+
+def check_backend_devices(backend: str, resources: Dict[str, float]) -> None:
+    """Refuse device capacity on a process-backend node."""
+    keys = device_keys(resources)
+    if backend == "process" and keys:
+        raise ProcessBackendDeviceError(
+            f"backend='process' cannot hold device capacity {keys}: one "
+            f"process per chip; declare device nodes on backend='thread'")

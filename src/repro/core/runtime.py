@@ -25,7 +25,7 @@ from repro.core.backends import (ExecutionBackend, ProcessBackend,
 from repro.core.memory import MemoryManager, ObjectReclaimedError
 from repro.core.object_store import (MISSING, ObjectStore,
                                      SharedMemoryStore)
-from repro.core.devices import device_keys
+from repro.core.devices import check_backend_devices, device_keys
 from repro.core.scheduler import (GlobalScheduler, LocalScheduler,
                                   UnschedulableActorError, _ref_ids)
 from repro.core.worker import (ActorContext, GetTimeoutError,
@@ -51,8 +51,7 @@ class DeviceLane:
     their *execution* to one dedicated thread per device key, so a
     kernel task never time-slices against ordinary cpu tasks in the
     shared worker pool and two kernel tasks never contend for the same
-    device context. Thread backend only — under the process backend the
-    ledger's capacity accounting is the sole (and sufficient) guard.
+    device context. Thread backend only: a chip belongs to one process.
     """
 
     def __init__(self, node: "Node", key: str):
@@ -154,13 +153,12 @@ class Node:
         else:
             self.backend = ThreadBackend(self, num_workers)
         self.backend.start()
-        # one dedicated executor lane per declared device key (thread
-        # backend): kernel tasks bypass the shared worker pool so they
-        # never time-slice against cpu tasks or each other on one device
-        self.device_lanes: Dict[str, DeviceLane] = {}
-        if backend != "process":
-            for key in device_keys(self.capacity):
-                self.device_lanes[key] = DeviceLane(self, key)
+        # one dedicated executor lane per declared device key (the
+        # cluster refuses device capacity on the process backend): kernel
+        # tasks bypass the shared worker pool so they never time-slice
+        # against cpu tasks or each other on one device
+        self.device_lanes: Dict[str, DeviceLane] = {
+            key: DeviceLane(self, key) for key in device_keys(self.capacity)}
 
     # ----------------------------------------------------------- heartbeats
 
@@ -545,6 +543,8 @@ class Cluster:
             raise ValueError(
                 f"unknown execution backend {backend!r}: expected "
                 f"'thread' or 'process'")
+        for declared in (node_resources or [resources_per_node or {}]):
+            check_backend_devices(backend, declared)
         # monotonic process-wide token: never reused across clusters (an
         # id() would be, after teardown), so per-cluster registration
         # guards compare against this
@@ -609,6 +609,7 @@ class Cluster:
         """Elastic scale-up: new nodes join by registering with the GCS."""
         w, spill, lat, cap, backend = self._node_defaults
         res = dict(resources or {"cpu": float(w)})
+        check_backend_devices(backend, res)
         node = Node(self, len(self.nodes), res, w, spill, lat, cap,
                     backend=backend)
         self.nodes.append(node)

@@ -1,11 +1,10 @@
-"""jit'd public wrapper: picks the Pallas kernel on TPU, interpret mode on
-CPU (tests), with the pure-XLA blockwise path as fallback."""
+"""Public wrapper: the Pallas kernel on TPU, interpret mode on CPU
+(tests), or the pure-jnp reference."""
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.flash_attention.kernel import flash_attention as _kernel
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.platform import use_interpret
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -13,8 +12,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     """backend: auto | pallas | interpret | ref."""
     if backend == "ref":
         return attention_ref(q, k, v, causal=causal, window=window)
-    if backend == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        backend = "pallas" if on_tpu else "interpret"
     return _kernel(q, k, v, causal=causal, window=window, bq=bq, bk=bk,
-                   interpret=(backend == "interpret"))
+                   interpret=use_interpret(backend))

@@ -37,12 +37,15 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, y_ref,
     li = li.reshape(bc)
     lf = lf.reshape(bc)
 
-    bcum = jnp.cumsum(lf)                                # (bc,)
-    m_run = m_scr[0, 0]
-    # intra-chunk log-decay matrix
-    logd = bcum[:, None] - bcum[None, :] + li[None, :]
     tri = (jax.lax.broadcasted_iota(jnp.int32, (bc, bc), 0)
            >= jax.lax.broadcasted_iota(jnp.int32, (bc, bc), 1))
+    # inclusive prefix sum as a masked row sum: Mosaic has no cumsum
+    bcum = jnp.sum(jnp.where(tri, lf[None, :], 0.0), axis=1)   # (bc,)
+    # the (1, 1) stabilizer scratch is read and written as a vector:
+    # Mosaic has no scalar VMEM loads or stores
+    m_run = jnp.max(m_scr[...])
+    # intra-chunk log-decay matrix
+    logd = bcum[:, None] - bcum[None, :] + li[None, :]
     logd = jnp.where(tri, logd, NEG)
     m_intra = logd.max(axis=1)
     m_new = jnp.maximum(m_intra, bcum + m_run)           # (bc,)
@@ -62,7 +65,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, y_ref,
     y_ref[0, 0] = (num / den[:, None]).astype(y_ref.dtype)
 
     # carry the state to the chunk end
-    btot = bcum[bc - 1]
+    btot = jnp.sum(lf)
     m_next = jnp.maximum(btot + m_run, (btot - bcum + li).max())
     w_upd = jnp.exp(btot - bcum + li - m_next)           # (bc,)
     decay = jnp.exp(btot + m_run - m_next)
@@ -71,7 +74,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, y_ref,
                                         (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32))
     n_scr[...] = decay * n_scr[...] + jnp.sum(k * w_upd[:, None], axis=0)
-    m_scr[0, 0] = m_next
+    m_scr[...] = jnp.broadcast_to(m_next, (1, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "interpret"))

@@ -1,9 +1,10 @@
 """Mamba selective-scan Pallas TPU kernel.
 
 Tiling: grid (B, di/bd, S/bc) with the sequence-chunk axis innermost; the
-(bd, ds) SSM state lives in VMEM scratch and is carried across chunks.
-Within a chunk the recurrence is stepped with a fori_loop over time while
-the chunk's (bc, bd) inputs/outputs stream HBM<->VMEM once — the memory-
+SSM state, held transposed as (ds, bd), lives in VMEM scratch and is
+carried across chunks. Within a chunk the recurrence is stepped with a
+fori_loop over tiles of ROWS time steps (unrolled within a tile), while
+the chunk's (bc, bd) blocks stream HBM<->VMEM once — the memory-
 bound structure Mamba prescribes (state never leaves SRAM/VMEM), re-blocked
 for TPU lanes: d_inner is tiled at 128 lanes, d_state (16) rides the
 sublane dimension.
@@ -18,7 +19,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_scr, *,
+# rows loaded per loop trip: one packed bf16 sublane tile (16 rows), a
+# multiple of the f32 tile (8) -- Mosaic loads only whole tiles at
+# dynamic offsets
+ROWS = 16
+
+
+def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, at_ref, d_ref, y_ref, h_scr, *,
                 bc: int):
     j = pl.program_id(2)
 
@@ -26,26 +33,25 @@ def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_scr, *,
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0].astype(jnp.float32)        # (bc, bd)
-    dt = dt_ref[0].astype(jnp.float32)      # (bc, bd)
-    b_t = b_ref[0].astype(jnp.float32)      # (bc, ds)
-    c_t = c_ref[0].astype(jnp.float32)      # (bc, ds)
-    a = a_ref[...].astype(jnp.float32)      # (bd, ds)
+    at = at_ref[...].astype(jnp.float32)    # (ds, bd)
     d = d_ref[...].astype(jnp.float32)      # (1, bd)
 
-    def step(t, carry):
-        h, ys = carry
-        decay = jnp.exp(dt[t][:, None] * a)                  # (bd, ds)
-        drive = (dt[t] * x[t])[:, None] * b_t[t][None, :]    # (bd, ds)
-        h = decay * h + drive
-        y = jnp.sum(h * c_t[t][None, :], axis=1) + d[0] * x[t]
-        return h, ys.at[t].set(y)
+    def tile(g, h):                         # h: (ds, bd)
+        rows = pl.ds(pl.multiple_of(g * ROWS, ROWS), ROWS)
+        x = x_ref[0, rows, :].astype(jnp.float32)        # (ROWS, bd)
+        dt = dt_ref[0, rows, :].astype(jnp.float32)      # (ROWS, bd)
+        b_t = b_ref[0, rows, :].astype(jnp.float32).T    # (ds, ROWS)
+        c_t = c_ref[0, rows, :].astype(jnp.float32).T    # (ds, ROWS)
+        ys = []
+        for r in range(ROWS):
+            x_r, dt_r = x[r:r + 1], dt[r:r + 1]          # (1, bd)
+            h = jnp.exp(dt_r * at) * h + b_t[:, r:r + 1] * (dt_r * x_r)
+            ys.append(jnp.sum(h * c_t[:, r:r + 1], axis=0, keepdims=True))
+        y = jnp.concatenate(ys, axis=0) + d * x
+        y_ref[0, rows, :] = y.astype(y_ref.dtype)
+        return h
 
-    h0 = h_scr[...]
-    ys0 = jnp.zeros((bc, x.shape[1]), jnp.float32)
-    h_last, ys = jax.lax.fori_loop(0, bc, step, (h0, ys0))
-    h_scr[...] = h_last
-    y_ref[0] = ys.astype(y_ref.dtype)
+    h_scr[...] = jax.lax.fori_loop(0, bc // ROWS, tile, h_scr[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "bc", "interpret"))
@@ -56,7 +62,7 @@ def ssm_scan(x, dt, b_t, c_t, a, d, *, bd: int = 128, bc: int = 256,
     ds = a.shape[1]
     bd = min(bd, di)
     bc = min(bc, s)
-    assert di % bd == 0 and s % bc == 0
+    assert di % bd == 0 and s % bc == 0 and bc % ROWS == 0
     nd, nc = di // bd, s // bc
 
     kernel = functools.partial(_ssm_kernel, bc=bc)
@@ -68,11 +74,11 @@ def ssm_scan(x, dt, b_t, c_t, a, d, *, bd: int = 128, bc: int = 256,
             pl.BlockSpec((1, bc, bd), lambda b, i, j: (b, j, i)),   # dt
             pl.BlockSpec((1, bc, ds), lambda b, i, j: (b, j, 0)),   # B
             pl.BlockSpec((1, bc, ds), lambda b, i, j: (b, j, 0)),   # C
-            pl.BlockSpec((bd, ds), lambda b, i, j: (i, 0)),         # A
+            pl.BlockSpec((ds, bd), lambda b, i, j: (0, i)),         # A^T
             pl.BlockSpec((1, bd), lambda b, i, j: (0, i)),          # D
         ],
         out_specs=pl.BlockSpec((1, bc, bd), lambda b, i, j: (b, j, i)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, di), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bd, ds), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((ds, bd), jnp.float32)],
         interpret=interpret,
-    )(x, dt, b_t, c_t, a, d.reshape(1, di))
+    )(x, dt, b_t, c_t, a.T, d.reshape(1, di))

@@ -33,10 +33,9 @@ from repro.analysis.hlo import analyze_hlo
 from repro.configs.base import ALL_SHAPES, ShapeConfig, shapes_for
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.launch import mesh as mesh_lib
+from repro.launch import train as train_lib
 from repro.models import build_model
-from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.parallel.sharding import make_rules
-from repro.train.train_step import make_train_step
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "dryrun_results"
 
@@ -59,18 +58,9 @@ def lower_cell(arch: str, shape: ShapeConfig, mesh, *, opt_overrides=None):
     in_data_shardings = rules.input_shardings(specs)
 
     if shape.kind == "train":
-        params_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        opt_shapes = jax.eval_shape(
-            partial(adamw_init, state_dtype=cfg.opt_state_dtype), params_shapes)
-        p_shard = rules.param_shardings(params_shapes)
-        o_shard = rules.opt_shardings(opt_shapes)
-        o_shard["step"] = rules.scalar_sharding()
-        step = make_train_step(model, AdamWConfig(state_dtype=cfg.opt_state_dtype))
-        fn = jax.jit(step,
-                     in_shardings=(p_shard, o_shard, in_data_shardings),
-                     out_shardings=(p_shard, o_shard, None),
-                     donate_argnums=(0, 1))
-        lowered = fn.lower(params_shapes, opt_shapes, specs)
+        init_fn, step_fn, _ = train_lib.build(cfg, mesh, shape)
+        state_shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+        lowered = step_fn.lower(*state_shapes, specs)
     elif shape.kind == "prefill":
         params_shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         p_shard = rules.param_shardings(params_shapes)
@@ -103,7 +93,8 @@ def run_cell(arch: str, shape: ShapeConfig, mesh_kind: str, *,
     n_dev = mesh.size
     t0 = time.time()
     rec = {"arch": arch, "shape": shape.name, "mesh": mesh_kind,
-           "devices": n_dev, "tag": tag, "ok": False}
+           "devices": n_dev, "device_kind": mesh_lib.PRODUCTION_DEVICE_KIND,
+           "tag": tag, "ok": False}
     try:
         lowered, meta = lower_cell(arch, shape, mesh, opt_overrides=opt_overrides)
         t_lower = time.time() - t0

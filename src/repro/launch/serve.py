@@ -9,6 +9,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import Request, ServingEngine
 
@@ -22,6 +23,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=8)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.smoke:
         cfg = cfg.scaled(param_dtype="float32")

@@ -83,13 +83,19 @@ class ServingEngine:
             lambda p, b: model.prefill(p, b, max_seq=max_seq))
         self._decode = jax.jit(model.decode_step)
 
+    def _greedy(self, logits):
+        """(B, V_padded) -> (B, 1) token ids; the logits past the real
+        vocabulary (padding for even sharding) are never chosen."""
+        real = logits[:, :self.model.cfg.vocab_size]
+        return jnp.argmax(real, axis=-1)[:, None].astype(jnp.int32)
+
     def _run_wave(self, wave: List[Request]) -> List[Response]:
         prompts = np.stack([r.prompt for r in wave])        # equal lengths
         b, s = prompts.shape
         budgets = np.array([r.max_new_tokens for r in wave])
         logits, cache = self._prefill(self.params,
                                       {"tokens": jnp.asarray(prompts)})
-        tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
+        tok = self._greedy(logits[:, -1])
         outs: List[List[int]] = [[] for _ in wave]
         for step in range(int(budgets.max())):
             alive = step < budgets
@@ -101,7 +107,7 @@ class ServingEngine:
                 break
             logits, cache = self._decode(self.params, cache, tok,
                                          jnp.int32(s + step))
-            tok = jnp.argmax(logits[:, 0], axis=-1)[:, None].astype(jnp.int32)
+            tok = self._greedy(logits[:, 0])
         now = time.perf_counter()
         return [Response(r.request_id, o, now - r.created)
                 for r, o in zip(wave, outs)]
@@ -113,6 +119,16 @@ class ServingEngine:
         for wave in length_aligned_waves(requests, max_wave):
             responses.extend(self._run_wave(wave))
         return responses
+
+    def warm(self, prompt_lens, max_wave: int) -> None:
+        """Compile every prefill and decode shape that waves of up to
+        `max_wave` prompts of these lengths use, so none compiles while
+        requests wait."""
+        for plen in sorted(set(prompt_lens)):
+            prompt = np.arange(plen, dtype=np.int32) % 7 + 1
+            for width in range(1, max_wave + 1):
+                self.serve([Request(0, prompt, max_new_tokens=2)] * width,
+                           max_wave=width)
 
     def generate(self, prompt: np.ndarray, max_new_tokens: int = 16
                  ) -> List[int]:

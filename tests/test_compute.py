@@ -113,6 +113,25 @@ def test_device_keys_helper():
     assert device_keys({"tpu": 2.0, "accel": 1.0}) == ("tpu", "accel")
 
 
+@pytest.mark.parametrize("declare", ["init", "add_node"])
+def test_process_backend_refuses_device_capacity(declare):
+    """A chip belongs to one process: device capacity on the process
+    backend is a typed error, raised before any worker is spawned."""
+    if declare == "init":
+        with pytest.raises(core.ProcessBackendDeviceError):
+            core.init(node_resources=[{"cpu": 1.0}, {"cpu": 1.0, "tpu": 1.0}],
+                      backend="process")
+        assert core.api._global["cluster"] is None
+        return
+    c = core.init(num_nodes=1, workers_per_node=1, backend="process")
+    try:
+        with pytest.raises(core.ProcessBackendDeviceError):
+            c.add_node({"cpu": 1.0, "gpu": 1.0})
+        assert len(c.nodes) == 1
+    finally:
+        core.shutdown()
+
+
 # ---------------------------------------------------------- kernel tasks
 
 def test_kernel_task_runs_and_profiles(hetero):

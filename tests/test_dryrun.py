@@ -2,7 +2,7 @@
 subprocess (8 placeholder host devices, (2,2,2) pod mesh), validating the
 whole dryrun path — shardings accepted, memory/cost analysis present,
 collectives parsed — without the 512-device production sweep (that runs
-via `python -m repro.launch.dryrun --all`, results in EXPERIMENTS.md)."""
+via `python -m repro.launch.dryrun --all`)."""
 import json
 import os
 import subprocess
@@ -28,9 +28,10 @@ SCRIPT = textwrap.dedent("""
     from repro.parallel.sharding import make_rules
     from repro.train.train_step import make_train_step
     from repro.analysis.hlo import analyze_hlo
+    from repro.launch.mesh import make_mesh
 
     arch = %(arch)r
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     shape = ShapeConfig("t", %(kind)r, %(seq)d, %(batch)d)
     cfg = get_smoke_config(arch).scaled(train_microbatch=0)
     rules = make_rules(mesh, cfg, shape)

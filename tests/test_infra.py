@@ -196,3 +196,45 @@ def test_serving_engine_greedy_decode():
     assert all(a.tokens == b.tokens for a, b in
                zip(sorted(resp, key=lambda r: r.request_id),
                    sorted(resp2, key=lambda r: r.request_id)))
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from a table keyed by `device_kind`; an unknown kind is
+    an error, never the v5e numbers by default."""
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "benchmarks" / "roofline.py"
+    spec = importlib.util.spec_from_file_location("roofline", path)
+    roofline = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(roofline)
+    assert roofline.peaks("TPU v5 lite")["bf16_flops"] == 197e12
+    with pytest.raises(KeyError, match="TPU v4"):
+        roofline.peaks("TPU v4")
+    with pytest.raises(KeyError):
+        roofline.roofline_terms({"devices": 1, "device_kind": "cpu",
+                                 "hlo_flops": 1.0, "hlo_bytes": 1.0,
+                                 "collective_bytes_total": 0.0})
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, where set, places the cache and no
+    directory is set in code; otherwise the fixed <checkout>/.jax_cache."""
+    from pathlib import Path
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

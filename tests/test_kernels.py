@@ -10,6 +10,7 @@ from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.int8_matmul import (int8_matmul, int8_matmul_ref,
                                        quantize_weights)
 from repro.kernels.mlstm_scan import mlstm_ref, mlstm_scan
+from repro.kernels.platform import use_interpret
 from repro.kernels.ssm_scan import ssm_scan, ssm_scan_ref
 
 TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
@@ -136,3 +137,23 @@ def test_int8_quantization_error_bounded():
     deq = wq.astype(jnp.float32) * sc[None, :]
     err = jnp.max(jnp.abs(deq - w) / (jnp.max(jnp.abs(w), axis=0)[None] + 1e-9))
     assert float(err) <= 1.0 / 127.0 + 1e-6
+
+
+# ---------------------------------------------------------- backend pick
+
+@pytest.mark.parametrize("platform,interpret", [("tpu", False),
+                                                ("cpu", True),
+                                                ("gpu", None)])
+def test_auto_backend_by_platform(monkeypatch, platform, interpret):
+    """`auto` compiles on TPU, interprets on the CPU, and refuses any
+    other platform instead of silently interpreting there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="no Pallas backend"):
+            use_interpret("auto")
+    else:
+        assert use_interpret("auto") is interpret
+    assert use_interpret("pallas") is False
+    assert use_interpret("interpret") is True
+    with pytest.raises(ValueError):
+        use_interpret("mosaic")
