@@ -111,7 +111,7 @@ def main(argv=None):
 
         # forward/backward is a device kernel task: jit-warmed at
         # registration, placed only where a gpu unit exists, timed as
-        # profiler "kernel" events
+        # profiler `kernel_task` spans
         warm = [batch_for_step(c, 0) for c in shard_cfgs]
         grad_shard = kernel_task(
             grad_shard_fn, resources={"gpu": 1.0}, num_returns=2,
@@ -145,8 +145,8 @@ def main(argv=None):
                       f"{ps.version} ({ps.total_bytes / 1e6:.1f} MB, "
                       f"{len(ps.shard_ids)} shards)")
         stats = profiler.summarize(cluster.gcs)
-        print(f"kernel tasks: {stats['kernel_tasks']:.0f}, mean on-device "
-              f"{stats['kernel_time_ms_mean']:.1f} ms, device waits "
+        print(f"kernel tasks: {stats['kernel_tasks']:.0f}, mean "
+              f"{stats['kernel_task_ms_mean']:.1f} ms each, device waits "
               f"{stats['device_waits']:.0f}, param publishes "
               f"{stats['param_publishes']:.0f}")
         core.shutdown()

@@ -12,7 +12,7 @@ Three pieces on top of the core runtime:
 - kernel tasks (`kernel.py`): `kernel_task` wraps a jax/Pallas callable
   into a `@remote`-style function that jit-warms at registration, runs
   on the device lane, blocks until the device is actually done, and
-  surfaces on-device milliseconds as profiler "kernel" events
+  is timed as a profiler `kernel_task` span
   (interpret-mode Pallas on CPU, so everything runs in CI);
 - sharded parameters (`params.py`): `ParamSet` packs a model pytree
   into contiguous per-shard buffers living in the object store

@@ -12,9 +12,11 @@ lane executes it. The wrapper:
   (``warmup_args=``), so the first cluster dispatch measures dispatch,
   not tracing;
 - blocks until the device has actually finished
-  (`jax.block_until_ready`) and logs a "kernel" event carrying the
-  on-device milliseconds, which `profiler.summarize` folds into
-  ``kernel_tasks`` / ``kernel_time_ms_mean``.
+  (`jax.block_until_ready`), inside a `kernel_task` span (host time from
+  the call to the device's finish: dispatch and wait included, so not a
+  kernel's device time, which only a profiler trace gives), which
+  `profiler.summarize` folds into ``kernel_tasks`` /
+  ``kernel_task_ms_mean``.
 
 Device tasks run on the thread backend only: a chip belongs to one
 process, so `core.init(backend="process")` refuses device capacity.
@@ -22,13 +24,13 @@ process, so `core.init(backend="process")` refuses device capacity.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 
+from repro.core import profiler
 from repro.core.api import RemoteFunction
-from repro.core.worker import current_node, current_task
+from repro.core.worker import current_task
 
 
 def _block(out: Any) -> Any:
@@ -44,16 +46,10 @@ def _block(out: Any) -> Any:
 def _instrument(fn, kernel_name: str):
     @functools.wraps(fn)
     def run(*args, **kwargs):
-        t0 = time.perf_counter()
-        out = _block(fn(*args, **kwargs))
-        ms = (time.perf_counter() - t0) * 1e3
-        node = current_node()
         spec = current_task()
-        if node is not None and spec is not None:
-            node.gcs.log_event("kernel", spec.task_id,
-                               f"node{node.node_id}", ms=ms,
-                               kernel=kernel_name)
-        return out
+        with profiler.span("kernel_task", "compute", kernel=kernel_name,
+                           task=spec.task_id if spec else ""):
+            return _block(fn(*args, **kwargs))
     return run
 
 

@@ -184,9 +184,9 @@
         a device-typed remote function: jit-compiled once (and
         optionally jit-warmed at registration via ``warmup_args=``),
         blocked on ``jax.block_until_ready`` so completion means the
-        device finished, and timed as profiler "kernel" events
+        device finished, and timed as profiler ``kernel_task`` spans
         (``profiler.summarize`` -> ``kernel_tasks`` /
-        ``kernel_time_ms_mean`` / ``device_waits``). The Pallas ops in
+        ``kernel_task_ms_mean`` / ``device_waits``). The Pallas ops in
         ``repro.kernels`` pick interpret mode off-TPU, so kernel tasks
         run everywhere CI does.
       * ``repro.compute.ParamSet`` publishes a parameter pytree as
@@ -270,8 +270,10 @@ _global: Dict[str, Optional[Cluster]] = {"cluster": None}
 
 
 def init(num_nodes: int = 2, workers_per_node: int = 2, **kw) -> Cluster:
+    from repro.core import profiler
     if _global["cluster"] is not None:
         shutdown()
+    profiler.watch_compiles()
     _global["cluster"] = Cluster(num_nodes, workers_per_node, **kw)
     return _global["cluster"]
 

@@ -576,13 +576,19 @@ class ControlPlane:
     # ------------------------------------------------------------ profiling
 
     def log_event(self, kind: str, task_id: str, where: str, **extra) -> None:
+        self.log_at(time.perf_counter(), kind, task_id, where, extra)
+
+    def log_at(self, t: float, kind: str, task_id: str, where: str,
+               extra: dict) -> None:
+        """Append an event stamped `t` (a `perf_counter` time, possibly
+        in the past: a closed span is logged at its start)."""
         stripe = getattr(self._event_tls, "stripe", None)
         if stripe is None:
             stripe = []
             self._event_tls.stripe = stripe
             with self._event_registry_lock:
                 self._event_stripes.append(stripe)
-        stripe.append((time.perf_counter(), kind, task_id, where, extra))
+        stripe.append((t, kind, task_id, where, extra))
 
     def events(self) -> List[Tuple[float, str, str, str, dict]]:
         with self._event_registry_lock:

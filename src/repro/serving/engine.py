@@ -35,7 +35,9 @@ from typing import Callable, Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.core import profiler
 from repro.models.model import Model
 
 
@@ -79,8 +81,12 @@ class ServingEngine:
         self.model = model
         self.params = params
         self.max_seq = max_seq
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, max_seq=max_seq))
+
+        def prefill(p, b):
+            return model.prefill(p, b, max_seq=max_seq)
+        # the programs' names in a profiler trace: jit_prefill and
+        # jit_decode_step
+        self._prefill = jax.jit(prefill)
         self._decode = jax.jit(model.decode_step)
 
     def _greedy(self, logits):
@@ -90,25 +96,50 @@ class ServingEngine:
         return jnp.argmax(real, axis=-1)[:, None].astype(jnp.int32)
 
     def _run_wave(self, wave: List[Request]) -> List[Response]:
+        """One wave, inside an `engine.wave` span whose counters say
+        where its host time went: `prefill_s` (prefill through the first
+        token's pull), `decode_s` (the rest), `sync_s` (blocked in the
+        later token pulls), `steps` (decode programs dispatched),
+        `lane_steps` (width x loop iterations) and `live_lane_steps`
+        (lanes still under budget, summed over the iterations)."""
         prompts = np.stack([r.prompt for r in wave])        # equal lengths
         b, s = prompts.shape
         budgets = np.array([r.max_new_tokens for r in wave])
-        logits, cache = self._prefill(self.params,
-                                      {"tokens": jnp.asarray(prompts)})
-        tok = self._greedy(logits[:, -1])
-        outs: List[List[int]] = [[] for _ in wave]
-        for step in range(int(budgets.max())):
-            alive = step < budgets
-            host_tok = np.asarray(tok)[:, 0]
-            for i in range(b):
-                if alive[i]:
-                    outs[i].append(int(host_tok[i]))
-            if step == budgets.max() - 1 or s + step >= self.max_seq - 1:
-                break
-            logits, cache = self._decode(self.params, cache, tok,
-                                         jnp.int32(s + step))
-            tok = self._greedy(logits[:, 0])
-        now = time.perf_counter()
+        with profiler.span("engine.wave", "engine", width=b, prompt_len=s,
+                           requests=[r.request_id for r in wave]) as sp:
+            t0 = time.perf_counter()
+            with TraceAnnotation("engine.prefill"):
+                logits, cache = self._prefill(
+                    self.params, {"tokens": jnp.asarray(prompts)})
+                tok = self._greedy(logits[:, -1])
+                host_tok = np.asarray(tok)[:, 0]
+            t1 = time.perf_counter()
+            outs: List[List[int]] = [[] for _ in wave]
+            steps = iters = 0
+            sync_s = 0.0
+            for step in range(int(budgets.max())):
+                if step:
+                    t = time.perf_counter()
+                    with TraceAnnotation("engine.token_sync"):
+                        host_tok = np.asarray(tok)[:, 0]
+                    sync_s += time.perf_counter() - t
+                alive = step < budgets
+                iters += 1
+                for i in range(b):
+                    if alive[i]:
+                        outs[i].append(int(host_tok[i]))
+                if step == budgets.max() - 1 or s + step >= self.max_seq - 1:
+                    break
+                with TraceAnnotation("engine.decode_dispatch"):
+                    logits, cache = self._decode(self.params, cache, tok,
+                                                 jnp.int32(s + step))
+                    tok = self._greedy(logits[:, 0])
+                steps += 1
+            now = time.perf_counter()
+            # a lane is live in the iterations before its budget runs out
+            sp.set(steps=steps, lane_steps=b * iters,
+                   live_lane_steps=int(np.minimum(budgets, iters).sum()),
+                   prefill_s=t1 - t0, decode_s=now - t1, sync_s=sync_s)
         return [Response(r.request_id, o, now - r.created)
                 for r, o in zip(wave, outs)]
 
@@ -153,8 +184,10 @@ class ServingReplica:
         engine's default."""
         self.waves_served += 1
         self.requests_served += len(requests)
-        return self.engine.serve(list(requests),
-                                 max_wave=max(len(requests), 1))
+        with profiler.span("replica.serve_wave", "runtime",
+                           requests=[r.request_id for r in requests]):
+            return self.engine.serve(list(requests),
+                                     max_wave=max(len(requests), 1))
 
     def stats(self) -> Dict[str, int]:
         return {"waves_served": self.waves_served,

@@ -37,8 +37,10 @@ Every disposition is observable: `serve_admit` / `serve_reject` /
 `serve_shed` / `serve_wave` / `serve_retry` / `serve_scale_up` /
 `serve_scale_down` / `serve_spare` events land in the control-plane log
 (surfaced by `profiler.summarize`), and an `SLOTracker` keeps sliding
-p50/p99 and goodput. Nothing here touches the task hot path: the front
-door is a control loop *above* submit/get/wait, one thread
+p50/p99 and goodput. Each request's wait in the queue is a
+`frontdoor.queued` span (`repro.core.profiler`), from admission to its
+wave's dispatch or its shedding. Nothing here touches the task hot
+path: the front door is a control loop *above* submit/get/wait, one thread
 ("frontdoor-ctl"), no runtime internals on the dispatch route — waves
 ride the same compiled per-replica graphs ReplicaPool uses.
 
@@ -55,6 +57,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import profiler
 from repro.serving.engine import Request, ServingReplica
 from repro.serving.slo import SLOTracker
 
@@ -187,7 +190,7 @@ class _Replica:
 # quantized, so priority has a window to matter in.
 class _Entry:
     __slots__ = ("deadline", "seq", "request", "ticket", "attempt",
-                 "priority", "_key")
+                 "priority", "_key", "queued")
 
     def __init__(self, deadline, seq, request, ticket, attempt=0,
                  priority=0, quantum=0.0):
@@ -202,6 +205,13 @@ class _Entry:
 
     def __lt__(self, other):
         return self._key < other._key
+
+    def enqueued(self) -> None:
+        """Open the `frontdoor.queued` span: from entering the queue to
+        its wave's dispatch (or its shedding)."""
+        self.queued = profiler.open_span(
+            "frontdoor.queued", "frontdoor",
+            request=self.request.request_id, attempt=self.attempt)
 
 
 class FrontDoor:
@@ -337,6 +347,7 @@ class FrontDoor:
             entry = _Entry(deadline, next(self._seq), request, ticket,
                            priority=getattr(request, "priority", 0),
                            quantum=self.priority_quantum_s)
+            entry.enqueued()
             heapq.heappush(
                 self._buckets.setdefault(len(request.prompt), []), entry)
             self._queued += 1
@@ -422,6 +433,7 @@ class FrontDoor:
                 if not heap:
                     del self._buckets[length]
         for e in shed:
+            e.queued.close(shed=True)
             self.slo.record_shed()
             self._gcs.log_event("serve_shed", f"req{e.request.request_id}",
                                 "frontdoor",
@@ -460,6 +472,10 @@ class FrontDoor:
                                 size=len(entries),
                                 replica=replica.handle.actor_id,
                                 batch_limit=replica.controller.size)
+            # the queue wait ends where the hand-off to the replica
+            # starts, just before `execute`
+            for e in entries:
+                e.queued.close(end=now, wave=ref.id)
             progressed = True
 
     def _pick_replica_locked(self) -> Optional[_Replica]:
@@ -577,6 +593,7 @@ class FrontDoor:
                 requeue.append(e)
         with self._cond:
             for e in requeue:
+                e.enqueued()
                 heapq.heappush(
                     self._buckets.setdefault(len(e.request.prompt), []), e)
                 self._queued += 1
@@ -668,6 +685,7 @@ class FrontDoor:
             self._queued = 0
             self._cond.notify_all()
         for e in drained:
+            e.queued.close(shed=True)
             self.slo.record_shed()
             e.ticket._fail(DeadlineShedError(
                 f"request {e.request.request_id} shed: front door closed"))

@@ -1,7 +1,6 @@
-"""Additional runtime/system coverage: profiler trace dump, API options,
+"""Additional runtime/system coverage: API options,
 object-store locality, BSP/hybrid executors, wait edge cases, DES elastic
 scaling, simulator latency percentiles."""
-import json
 import time
 
 import pytest
@@ -69,18 +68,6 @@ def test_object_locality_transfer(cluster):
     assert out == sum(range(100))
     # after consumption the object may be resident on >= 1 node
     assert len(cluster.gcs.locations(ref.id)) >= 1
-
-
-def test_chrome_trace_dump(tmp_path, cluster):
-    @core.remote
-    def f():
-        return 1
-    core.get(f.submit())
-    from repro.core.profiler import dump_chrome_trace
-    p = tmp_path / "trace.json"
-    dump_chrome_trace(cluster.gcs, str(p))
-    data = json.loads(p.read_text())
-    assert len(data["traceEvents"]) > 0
 
 
 def test_bsp_executor_barrier_semantics():
