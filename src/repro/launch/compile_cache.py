@@ -18,9 +18,15 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 def enable_compile_cache() -> str:
     """Turn on the persistent compilation cache; returns its directory.
 
+    Every program is kept, however quickly it compiled: JAX's default
+    keeps only those that took a second or more, and a program that
+    compiles in half a second (a decode step on the chip) would be
+    compiled again by every process.
+
     `JAX_COMPILATION_CACHE_DIR`, where set, places the cache: JAX reads
     that variable itself, so no directory is set here. Otherwise the
     cache goes to `<checkout>/.jax_cache`."""
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense_init, l2norm
+from repro.models.layers import (apply_rope, dense_init, l2norm,
+                                 layer_read, layer_write)
 
 Params = Dict[str, Any]
 NEG_INF = -1e30
@@ -59,18 +60,38 @@ def _mask_bias(q_pos, kv_pos, window: int, causal: bool):
     return jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def naive_sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True,
-               softcap: float = 0.0) -> jnp.ndarray:
-    """q: (B,S,Kv,G,hd); k,v: (B,T,Kv,hd). Returns (B,S,Kv,G,hd)."""
-    hd = q.shape[-1]
-    scale = 1.0 / math.sqrt(hd)
-    s = jnp.einsum("bskgh,btkh->bkgst", q, k,
-                   preferred_element_type=jnp.float32) * scale
+def _softmax_weights(s, q_pos, kv_pos, window: int, causal: bool,
+                     softcap: float, dtype):
+    """float32 scores (B,Kv,G,S,T) -> masked softmax weights in `dtype`."""
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     s = s + _mask_bias(q_pos, kv_pos, window, causal)[None, None, None]
-    w = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jax.nn.softmax(s, axis=-1).astype(dtype)
+
+
+def naive_sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True,
+               softcap: float = 0.0) -> jnp.ndarray:
+    """q: (B,S,Kv,G,hd); k,v: (B,T,Kv,hd). Returns (B,S,Kv,G,hd)."""
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    s = jnp.einsum("bskgh,btkh->bkgst", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    w = _softmax_weights(s, q_pos, kv_pos, window, causal, softcap, q.dtype)
     return jnp.einsum("bkgst,btkh->bskgh", w, v)
+
+
+def cached_sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0,
+                softcap: float = 0.0) -> jnp.ndarray:
+    """naive_sdpa over a decode cache stored heads before positions.
+    q: (B,S,Kv,G,hd); k,v: (B,Kv,hd,T). Returns (B,S,Kv,G,hd)."""
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    # One lane at a time: the float32 products take K in float32, and all
+    # lanes at once would hold a layer's K so (134 MB at width 8).
+    s = jax.lax.map(
+        lambda kq: jnp.einsum("kht,skgh->kgst", *kq,
+                              preferred_element_type=jnp.float32),
+        (k, q)) * scale
+    w = _softmax_weights(s, q_pos, kv_pos, window, True, softcap, q.dtype)
+    return jnp.einsum("bkgst,bkht->bskgh", w, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
@@ -235,12 +256,20 @@ def sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True,
 
 # ============================================================ GQA forward
 
-def _qkv(params: Params, cfg: ModelConfig, x, positions):
+def _qkv(params: Params, cfg: ModelConfig, x, positions, *,
+         pin_layout: bool = False):
     B, S, _ = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = jnp.einsum("bsd,de->bse", x, params["w_q"]).reshape(B, S, h, hd)
-    k = jnp.einsum("bsd,de->bse", x, params["w_k"]).reshape(B, S, kv, hd)
-    v = jnp.einsum("bsd,de->bse", x, params["w_v"]).reshape(B, S, kv, hd)
+    q, k, v = (jnp.einsum("bsd,de->bse", x, params[n])
+               for n in ("w_q", "w_k", "w_v"))
+    if pin_layout:
+        # Decode: keep the projections in the layout their matmuls give.
+        # Left free, XLA lets rope and the cache write choose it and, for
+        # waves wider than one lane, transposes a layer of w_q and w_k
+        # every step to produce it (8 MB each at stablelm-1.6b).
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+    q, k, v = (q.reshape(B, S, h, hd), k.reshape(B, S, kv, hd),
+               v.reshape(B, S, kv, hd))
     if cfg.qk_norm:
         q, k = l2norm(q), l2norm(k)
     q = apply_rope(q, positions, cfg.rope_theta, cfg.partial_rotary_factor)
@@ -281,6 +310,12 @@ def attention_forward(params: Params, cfg: ModelConfig, x, *, window: int = 0,
 
 
 # ============================================================ decode caches
+#
+# A layer's K and V are stored heads before positions, (B, Kv*hd, cap), and
+# stacked over the layers of a scanned group, (L, B, Kv*hd, cap): a decode
+# step writes the new token's column in place and attention reads the
+# layer where it lies. `slot_pos` (cap,) holds each slot's absolute
+# position (-1 = empty); sliding-window caches are rings of cap = window.
 
 def init_attn_cache(cfg: ModelConfig, batch: int, max_seq: int, *,
                     window: int = 0, dtype=None) -> Params:
@@ -288,10 +323,20 @@ def init_attn_cache(cfg: ModelConfig, batch: int, max_seq: int, *,
     dt = dtype or jnp.dtype(cfg.param_dtype)
     kv, hd = cfg.num_kv_heads, cfg.head_dim
     return {
-        "k": jnp.zeros((batch, cap, kv, hd), dt),
-        "v": jnp.zeros((batch, cap, kv, hd), dt),
+        "k": jnp.zeros((batch, kv * hd, cap), dt),
+        "v": jnp.zeros((batch, kv * hd, cap), dt),
         "slot_pos": jnp.full((cap,), -1, jnp.int32),
     }
+
+
+def _ring(x, cap: int, axis: int):
+    """The last `cap` entries of `x` along `axis`, entry i at slot i % cap
+    (absolute index i); fewer than `cap` entries fill the first slots."""
+    n = x.shape[axis]
+    if n <= cap:
+        return x, n
+    x = jax.lax.slice_in_dim(x, n - cap, n, axis=axis)
+    return jnp.roll(x, n % cap, axis=axis), cap
 
 
 def attention_prefill(params: Params, cfg: ModelConfig, x, *, window: int = 0,
@@ -309,38 +354,50 @@ def attention_prefill(params: Params, cfg: ModelConfig, x, *, window: int = 0,
     out = jnp.einsum("bse,ed->bsd", out, params["w_o"])
 
     cap = min(window, max_seq) if window > 0 else max_seq
-    cache = init_attn_cache(cfg, B, max_seq, window=window, dtype=k.dtype)
-    take = min(S, cap)
-    idx = jnp.arange(S - take, S)
-    slots = idx % cap
+    kv_width = cfg.num_kv_heads * cfg.head_dim
+
+    def heads_first(a):                          # (B,S,Kv,hd) -> (B,Kv*hd,cap)
+        a, n = _ring(a.reshape(B, S, kv_width).swapaxes(1, 2), cap, 2)
+        return jnp.pad(a, ((0, 0), (0, 0), (0, cap - n)))
+
+    slot_pos, n = _ring(positions.astype(jnp.int32), cap, 0)
     cache = {
-        "k": cache["k"].at[:, slots].set(k[:, idx]),
-        "v": cache["v"].at[:, slots].set(v[:, idx]),
-        "slot_pos": cache["slot_pos"].at[slots].set(idx),
+        "k": heads_first(k),
+        "v": heads_first(v),
+        "slot_pos": jnp.pad(slot_pos, (0, cap - n), constant_values=-1),
     }
     return out, cache
 
 
 def attention_decode(params: Params, cfg: ModelConfig, x, cache: Params,
-                     pos, *, window: int = 0) -> Tuple[jnp.ndarray, Params]:
-    """x: (B,1,d); pos: scalar int32 (position of the new token)."""
+                     layer, pos, *, window: int = 0
+                     ) -> Tuple[jnp.ndarray, Params]:
+    """x: (B,1,d); cache: leaves stacked over layers, this one `layer`;
+    pos: scalar int32 (position of the new token). Writes the new token's
+    K/V column and slot position at (layer, ..., pos % cap) and attends
+    over the layer's slots."""
     B = x.shape[0]
     kv, hd = cfg.num_kv_heads, cfg.head_dim
-    positions = pos[None] if jnp.ndim(pos) == 0 else pos
-    q, k, v = _qkv(params, cfg, x, jnp.reshape(positions, (1,)))
-    cap = cache["k"].shape[1]
+    pos1 = jnp.reshape(pos, (1,))
+    q, k, v = _qkv(params, cfg, x, pos1, pin_layout=True)
+    cap = cache["k"].shape[-1]
     slot = jnp.mod(pos, cap)
-    k_cache = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
-    slot_pos = jax.lax.dynamic_update_slice(
-        cache["slot_pos"], jnp.reshape(pos, (1,)).astype(jnp.int32), (slot,))
+    cache = {
+        "k": layer_write(cache["k"], layer, k.reshape(B, kv * hd, 1),
+                         (0, 0, slot)),
+        "v": layer_write(cache["v"], layer, v.reshape(B, kv * hd, 1),
+                         (0, 0, slot)),
+        "slot_pos": layer_write(cache["slot_pos"], layer,
+                                pos1.astype(jnp.int32), (slot,)),
+    }
+    k_l, v_l = (layer_read(cache[n], layer).reshape(B, kv, hd, cap)
+                for n in ("k", "v"))
     qg = q.reshape(B, 1, kv, cfg.q_per_kv, hd)
-    out = naive_sdpa(qg, k_cache, v_cache, jnp.reshape(pos, (1,)), slot_pos,
-                     window=window, causal=True,
-                     softcap=cfg.attn_logit_softcap)
+    out = cached_sdpa(qg, k_l, v_l, pos1, layer_read(cache["slot_pos"], layer),
+                      window=window, softcap=cfg.attn_logit_softcap)
     out = out.reshape(B, 1, cfg.num_heads * cfg.head_dim)
     out = jnp.einsum("bse,ed->bsd", out, params["w_o"])
-    return out, {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
+    return out, cache
 
 
 # ========================================================== cross-attention
@@ -458,10 +515,13 @@ def mla_prefill(params: Params, cfg: ModelConfig, x, *, max_seq: int = 0):
     return out, cache
 
 
-def mla_decode(params: Params, cfg: ModelConfig, x, cache: Params, pos):
+def mla_decode(params: Params, cfg: ModelConfig, x, cache: Params, layer,
+               pos):
     """Absorbed-matmul MLA decode: attention runs entirely in the latent
     space (q absorbed through W_UK, context expanded through W_UV afterwards),
-    so per-token KV traffic is kv_lora+rope instead of 2*h*hd.
+    so per-token KV traffic is kv_lora+rope instead of 2*h*hd. The cache's
+    leaves are stacked over layers; the new token is written at
+    (layer, ..., pos) in place.
     """
     m = cfg.mla
     B = x.shape[0]
@@ -469,10 +529,14 @@ def mla_decode(params: Params, cfg: ModelConfig, x, cache: Params, pos):
     pos1 = jnp.reshape(pos, (1,))
     q_nope, q_rope = _mla_q(params, cfg, x, pos1)                  # (B,1,h,*)
     c_kv_new, k_rope_new = _mla_ckv(params, cfg, x, pos1)
-    c_kv = jax.lax.dynamic_update_slice(cache["c_kv"], c_kv_new, (0, pos, 0))
-    k_rope = jax.lax.dynamic_update_slice(cache["k_rope"], k_rope_new, (0, pos, 0))
-    slot_pos = jax.lax.dynamic_update_slice(
-        cache["slot_pos"], pos1.astype(jnp.int32), (pos,))
+    cache = {
+        "c_kv": layer_write(cache["c_kv"], layer, c_kv_new, (0, pos)),
+        "k_rope": layer_write(cache["k_rope"], layer, k_rope_new, (0, pos)),
+        "slot_pos": layer_write(cache["slot_pos"], layer,
+                                pos1.astype(jnp.int32), (pos,)),
+    }
+    c_kv, k_rope, slot_pos = (layer_read(cache[n], layer)
+                              for n in ("c_kv", "k_rope", "slot_pos"))
 
     if m.absorb_decode:
         # q_c[b,h,r] = sum_e q_nope[b,h,e] W_uk[r,h,e]
@@ -499,4 +563,4 @@ def mla_decode(params: Params, cfg: ModelConfig, x, cache: Params, pos):
         out = out.reshape(B, 1, h, m.v_head_dim)
     out = out.reshape(B, 1, h * m.v_head_dim)
     out = jnp.einsum("bse,ed->bsd", out, params["w_o"])
-    return out, {"c_kv": c_kv, "k_rope": k_rope, "slot_pos": slot_pos}
+    return out, cache

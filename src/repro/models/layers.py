@@ -127,6 +127,25 @@ def unembed(params: Params, x: jnp.ndarray, tied: bool,
     return jnp.einsum("...d,dv->...v", x, head)
 
 
+# ---------------------------------------------------------------- caches
+# A decode cache leaf is stacked over layers: (L, ...). A layer reads its
+# own slice and writes only what it changed, at its own index, so the
+# decode program updates the cache in place.
+
+def layer_read(leaf: jnp.ndarray, layer) -> jnp.ndarray:
+    """Layer `layer` of the stacked `leaf`."""
+    return jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+
+
+def layer_write(leaf: jnp.ndarray, layer, value: jnp.ndarray,
+                at=()) -> jnp.ndarray:
+    """`leaf` with `value` written into layer `layer`, at offset `at`
+    inside the layer (zeros for the dimensions `at` leaves out)."""
+    at = tuple(at) + (0,) * (value.ndim - len(at))
+    return jax.lax.dynamic_update_slice(
+        leaf, value[None].astype(leaf.dtype), (layer,) + at)
+
+
 # ---------------------------------------------------------------- loss
 
 def softmax_xent(logits: jnp.ndarray, labels: jnp.ndarray,

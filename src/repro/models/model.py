@@ -31,8 +31,9 @@ from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models import xlstm as xlstm_lib
 from repro.models.layers import (dense_init, embedding_init, embed_tokens,
-                                 rmsnorm, rmsnorm_init, softmax_xent,
-                                 swiglu, swiglu_init, unembed)
+                                 layer_read, layer_write, rmsnorm,
+                                 rmsnorm_init, softmax_xent, swiglu,
+                                 swiglu_init, unembed)
 
 Params = Dict[str, Any]
 
@@ -412,31 +413,35 @@ class Model:
     # ------------------------------------------------------------- decode
 
     def _layer_decode(self, lp: Params, kind: str, ffn_kind: str, h, cache,
-                      pos):
+                      layer, pos):
+        """One layer's step. `cache`: this pattern position's leaves,
+        stacked over layers; the layer writes what it changed at `layer`
+        and returns the updated stack."""
         cfg = self.cfg
         mix_in = rmsnorm(lp["pre_norm"], h, cfg.norm_eps)
-        cross = {k: cache[k] for k in ("cross_k", "cross_v") if k in cache}
         core = {k: v for k, v in cache.items() if not k.startswith("cross_")}
         if kind in (ATTN, SWA):
             window = cfg.window_size if kind == SWA else 0
             out, core = attn.attention_decode(lp["mixer"], cfg, mix_in, core,
-                                              pos, window=window)
+                                              layer, pos, window=window)
         elif kind == MLA:
-            out, core = attn.mla_decode(lp["mixer"], cfg, mix_in, core, pos)
-        elif kind == MAMBA:
-            out, core = ssm_lib.mamba_decode(lp["mixer"], cfg, mix_in, core)
-        elif kind == MLSTM:
-            out, core = xlstm_lib.mlstm_decode(lp["mixer"], cfg, mix_in, core)
-        elif kind == SLSTM:
-            out, core = xlstm_lib.slstm_decode(lp["mixer"], cfg, mix_in, core)
+            out, core = attn.mla_decode(lp["mixer"], cfg, mix_in, core, layer,
+                                        pos)
         else:
-            raise ValueError(kind)
+            # a recurrent state is small: the layer writes it whole
+            step = {MAMBA: ssm_lib.mamba_decode, MLSTM: xlstm_lib.mlstm_decode,
+                    SLSTM: xlstm_lib.slstm_decode}[kind]
+            state = {n: layer_read(a, layer) for n, a in core.items()}
+            out, state = step(lp["mixer"], cfg, mix_in, state)
+            core = {n: layer_write(a, layer, state[n])
+                    for n, a in core.items()}
         h = h + out
-        if cross:
+        if "cross_k" in cache:
             c_in = rmsnorm(lp["cross_norm"], h, cfg.norm_eps)
-            h = h + attn.cross_attention_forward(
-                lp["cross"], cfg, c_in, {"k": cross["cross_k"],
-                                         "v": cross["cross_v"]})
+            enc_kv = {n: layer_read(cache[f"cross_{n}"], layer)
+                      for n in ("k", "v")}
+            h = h + attn.cross_attention_forward(lp["cross"], cfg, c_in,
+                                                 enc_kv)
         if "ffn" in lp and ffn_kind != NONE:
             f_in = rmsnorm(lp["post_norm"], h, cfg.norm_eps)
             if ffn_kind == MOE and "router" in lp["ffn"]:
@@ -445,34 +450,44 @@ class Model:
             else:
                 y = swiglu(lp["ffn"], f_in)
             h = h + y
-        return h, dict(core, **cross)
+        return h, dict(cache, **core)
 
     def decode_step(self, params: Params, cache: Params, tokens, pos):
-        """tokens: (B,1) int32; pos: scalar int32 -> (logits (B,1,V), cache)."""
+        """One token for every lane: tokens (B,1) int32, pos a scalar int32
+        (the new token's position) -> (logits (B,1,V), cache).
+
+        The cache passed in is consumed: the step updates it in place
+        (`ServingEngine` donates it), so keep only the one returned. Its
+        scanned groups' leaves are stacked over the groups and carried
+        through the layer scan; each layer writes only its new state at its
+        own index. Attention's K and V are (L, B, Kv*hd, cap), heads x
+        head-dim before positions, one column written per step; MLA's
+        c_kv/k_rope (L, B, cap, r); recurrent states whole per layer."""
         cfg = self.cfg
         h = embed_tokens(params["embed"], tokens)
         new_cache: Params = {}
         if cfg.first_k_dense:
             new_first = []
-            for i, lp in enumerate(params["first"]):
-                h, c = self._layer_decode(lp, cfg.pattern[0], DENSE, h,
-                                          cache["first"][i], pos)
-                new_first.append(c)
+            for lp, c in zip(params["first"], cache["first"]):
+                h, c = self._layer_decode(
+                    lp, cfg.pattern[0], DENSE, h,
+                    jax.tree.map(lambda a: a[None], c), 0, pos)
+                new_first.append(jax.tree.map(lambda a: a[0], c))
             new_cache["first"] = new_first
 
-        def group_body(hh, xs):
-            g_params, g_cache = xs
-            new_g = []
+        def group_body(carry, xs):
+            hh, g_cache = carry
+            g_params, layer = xs
+            g_cache = list(g_cache)
             for i, kind in enumerate(cfg.pattern):
-                hh, c = self._layer_decode(g_params[i], kind,
-                                           cfg.ffn_pattern[i], hh,
-                                           g_cache[i], pos)
-                new_g.append(c)
-            return hh, tuple(new_g)
+                hh, g_cache[i] = self._layer_decode(
+                    g_params[i], kind, cfg.ffn_pattern[i], hh, g_cache[i],
+                    layer, pos)
+            return (hh, tuple(g_cache)), None
 
-        h, groups_cache = jax.lax.scan(group_body, h,
-                                       (params["groups"], cache["groups"]))
-        new_cache["groups"] = groups_cache
+        (h, new_cache["groups"]), _ = jax.lax.scan(
+            group_body, (h, cache["groups"]),
+            (params["groups"], jnp.arange(cfg.num_groups, dtype=jnp.int32)))
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
         logits = unembed(params["embed"], h, cfg.tie_embeddings,
                          params.get("lm_head"))

@@ -237,7 +237,13 @@ class ShardingRules:
 
         if name == "slot_pos":
             return out(*([None] * len(base)))
-        if name in ("k", "v", "cross_k", "cross_v"):   # (B, cap, kv, hd)
+        if name in ("k", "v"):                         # (B, kv*hd, cap)
+            if self.cfg.num_kv_heads % mesh.shape[tp] == 0:
+                return out(bat, tp, seq)
+            # kv heads don't divide TP: shard the positions over `model`
+            cap_axes = ((self.fsdp, tp) if self.seq_shard else tp)
+            return out(bat, None, cap_axes)
+        if name in ("cross_k", "cross_v"):             # (B, T, kv, hd)
             kv = base[2]
             if kv % mesh.shape[tp] == 0:
                 return out(bat, seq, tp, None)

@@ -85,9 +85,10 @@ class ServingEngine:
         def prefill(p, b):
             return model.prefill(p, b, max_seq=max_seq)
         # the programs' names in a profiler trace: jit_prefill and
-        # jit_decode_step
+        # jit_decode_step. The cache is donated to the decode step, which
+        # updates it in place.
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(model.decode_step)
+        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
 
     def _greedy(self, logits):
         """(B, V_padded) -> (B, 1) token ids; the logits past the real
@@ -100,8 +101,10 @@ class ServingEngine:
         where its host time went: `prefill_s` (prefill through the first
         token's pull), `decode_s` (the rest), `sync_s` (blocked in the
         later token pulls), `steps` (decode programs dispatched),
-        `lane_steps` (width x loop iterations) and `live_lane_steps`
-        (lanes still under budget, summed over the iterations)."""
+        `donated_steps` (those after which the cache passed in was
+        deleted: the runtime took the donation), `lane_steps` (width x
+        loop iterations) and `live_lane_steps` (lanes still under budget,
+        summed over the iterations)."""
         prompts = np.stack([r.prompt for r in wave])        # equal lengths
         b, s = prompts.shape
         budgets = np.array([r.max_new_tokens for r in wave])
@@ -115,7 +118,7 @@ class ServingEngine:
                 host_tok = np.asarray(tok)[:, 0]
             t1 = time.perf_counter()
             outs: List[List[int]] = [[] for _ in wave]
-            steps = iters = 0
+            steps = donated = iters = 0
             sync_s = 0.0
             for step in range(int(budgets.max())):
                 if step:
@@ -131,13 +134,15 @@ class ServingEngine:
                 if step == budgets.max() - 1 or s + step >= self.max_seq - 1:
                     break
                 with TraceAnnotation("engine.decode_dispatch"):
+                    probe = jax.tree.leaves(cache)[0]
                     logits, cache = self._decode(self.params, cache, tok,
                                                  jnp.int32(s + step))
                     tok = self._greedy(logits[:, 0])
                 steps += 1
+                donated += probe.is_deleted()
             now = time.perf_counter()
             # a lane is live in the iterations before its budget runs out
-            sp.set(steps=steps, lane_steps=b * iters,
+            sp.set(steps=steps, donated_steps=donated, lane_steps=b * iters,
                    live_lane_steps=int(np.minimum(budgets, iters).sum()),
                    prefill_s=t1 - t0, decode_s=now - t1, sync_s=sync_s)
         return [Response(r.request_id, o, now - r.created)

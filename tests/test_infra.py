@@ -154,6 +154,31 @@ def test_sharding_rules_divisibility_never_invalid():
         jax.tree_util.tree_map_with_path(check, shapes)
 
 
+@pytest.mark.parametrize("tp, batch, want", [
+    (2, 8, ("data", "model", None)),     # kv heads divide TP: Kv*hd sharded
+    (16, 8, ("data", None, "model")),    # they don't: positions instead
+    (2, 1, (None, "model", "data")),     # one lane: positions over data
+])
+def test_cache_spec_follows_kv_layout(tp, batch, want):
+    """K/V cache leaves are (L, B, Kv*hd, cap): `_cache_spec` puts TP on
+    the kv heads when they divide it, on the positions otherwise."""
+    from jax.sharding import PartitionSpec as P
+    from repro.configs.base import ShapeConfig
+    from repro.configs.registry import get_config
+    from repro.parallel.sharding import make_rules
+
+    class FakeMesh:
+        axis_names = ("data", "model")
+        shape = {"data": 4, "model": tp}
+
+    cfg = get_config("mistral-large-123b")           # 8 kv heads of 128
+    rules = make_rules(FakeMesh(), cfg, ShapeConfig("d", "decode", 4096,
+                                                    batch))
+    shape = (cfg.num_groups, batch, cfg.num_kv_heads * cfg.head_dim, 4096)
+    for leaf in ("k", "v"):
+        assert rules._cache_spec(f"groups/0/{leaf}", shape) == P(None, *want)
+
+
 def test_compressing_train_step_converges():
     from repro.configs.registry import get_smoke_config
     from repro.models import build_model
@@ -223,6 +248,7 @@ def test_compile_cache_placement(monkeypatch, env_dir):
     from pathlib import Path
     from repro.launch.compile_cache import enable_compile_cache
     before = jax.config.jax_compilation_cache_dir
+    min_s = jax.config.jax_persistent_cache_min_compile_time_secs
     if env_dir is None:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     else:
@@ -236,5 +262,8 @@ def test_compile_cache_placement(monkeypatch, env_dir):
         else:
             assert got == env_dir
             assert jax.config.jax_compilation_cache_dir == before
+        # every program is kept, the quick compiles too
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)

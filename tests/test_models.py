@@ -9,6 +9,7 @@ import pytest
 from repro.configs.registry import ARCH_IDS, get_smoke_config
 from repro.models import build_model, padded_vocab
 from repro.optim.adamw import AdamWConfig, adamw_init
+from repro.serving import ServingEngine
 from repro.train.train_step import make_train_step
 
 
@@ -85,6 +86,57 @@ def test_prefill_decode_matches_forward(arch):
             np.asarray(logits[:, 0], np.float32),
             np.asarray(full_logits[:, t], np.float32),
             rtol=2e-3, atol=2e-3, err_msg=f"{arch} pos {t}")
+
+
+#: one registry model per kind of decode cache
+DECODER_KINDS = {"attn": "stablelm-1.6b", "swa": "gemma3-12b",
+                 "mla": "deepseek-v2-236b", "mamba": "jamba-1.5-large-398b",
+                 "xlstm": "xlstm-125m", "cross": "seamless-m4t-medium"}
+
+
+@pytest.mark.parametrize("kind", DECODER_KINDS)
+def test_decode_step_updates_donated_cache_in_place(kind):
+    """The engine's decode program aliases every cache byte to its output
+    and consumes the cache passed in; a step changes only the new token's
+    slot of each attention layer (the K/V column, its `slot_pos`)."""
+    cfg = get_smoke_config(DECODER_KINDS[kind])
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(model, params, max_seq=16)
+    b, pos = 2, 21                                 # swa rings wrap: slot 5
+
+    def filled():
+        return jax.tree.map(lambda a: a + 1, model.init_cache(b, 16))
+
+    # a NumPy view would pin the cache's buffers and refuse the donation
+    before = jax.tree.map(np.asarray, filled())
+    cache = filled()
+    tok = jnp.ones((b, 1), jnp.int32)
+    compiled = eng._decode.lower(params, cache, tok, jnp.int32(pos)).compile()
+    nbytes = sum(a.nbytes for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+
+    _, after = eng._decode(params, cache, tok, jnp.int32(pos))
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+    for c0, c1 in zip(before["groups"], after["groups"]):
+        assert {n: (a.shape, a.dtype) for n, a in c0.items()} == \
+            {n: (a.shape, a.dtype) for n, a in c1.items()}
+        if "k" in c0:                              # (L, B, Kv*hd, cap)
+            assert c0["k"].shape[2] == cfg.num_kv_heads * cfg.head_dim
+            slot = pos % c0["k"].shape[-1]
+            for n in ("k", "v"):
+                kept = np.delete(np.asarray(c1[n]), slot, axis=-1)
+                np.testing.assert_array_equal(
+                    kept, np.delete(c0[n], slot, axis=-1))
+                assert np.any(np.asarray(c1[n])[..., slot] != c0[n][..., slot])
+            sp = np.asarray(c1["slot_pos"])
+            assert (sp[:, slot] == pos).all()
+            np.testing.assert_array_equal(np.delete(sp, slot, axis=-1),
+                                          np.delete(c0["slot_pos"], slot,
+                                                    axis=-1))
+        for n in ("cross_k", "cross_v"):
+            if n in c0:
+                np.testing.assert_array_equal(np.asarray(c1[n]), c0[n])
 
 
 def test_loss_decreases_when_training():

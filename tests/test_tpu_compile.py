@@ -7,6 +7,8 @@ until it exits. So the topology is described inside a module fixture of
 this one file, never while a module is imported: every xdist worker then
 collects the same tests, and only the worker given this file loads it.
 """
+import math
+import re
 from functools import partial
 
 import jax
@@ -15,12 +17,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ShapeConfig
-from repro.configs.registry import get_config
+from repro.configs.registry import get_config, get_smoke_config
 from repro.kernels import flash_attention, int8_matmul, mlstm_scan, ssm_scan
 from repro.launch import train as train_launcher
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serving import ServingEngine
+from test_models import DECODER_KINDS
 
 HBM_BYTES = 16e9            # one v5e chip
 SERVE_ARCH = "stablelm-1.6b"
@@ -81,6 +84,63 @@ def test_serving_engine_compiles_for_one_chip(one_chip, program):
                     jax.eval_shape(lambda: model.init_cache(4, 1024)))
         lowered = eng._decode.lower(params, cache, i32((4, 1)), i32(()))
     assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
+def _decode_for_one_chip(one_chip, cfg, width: int, max_seq: int):
+    """The ServingEngine's decode step compiled for one chip, and the
+    shapes of the cache it takes."""
+    model = build_model(cfg)
+    params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    eng = ServingEngine(model, params, max_seq=max_seq)
+    cache = _on(one_chip,
+                jax.eval_shape(lambda: model.init_cache(width, max_seq)))
+    i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
+    compiled = eng._decode.lower(params, cache, i32((width, 1)),
+                                 i32(())).compile()
+    return compiled, cache
+
+
+def _cache_bytes(cache) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+
+
+def _big_copies(hlo: str, elements: int):
+    """The `copy` instructions of an optimised HLO text whose result has
+    at least `elements` elements."""
+    out = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(", hlo):
+        n = math.prod(int(d) for d in m.group(1).split(",") if d)
+        if n >= elements:
+            out.append(m.group(0))
+    return out
+
+
+@pytest.mark.parametrize("width", [1, 8])
+def test_decode_updates_kv_cache_in_place(one_chip, width):
+    """stablelm-1.6b's decode step at max_seq 2048: the whole cache is
+    aliased from input to output, the temporaries stay under one layer's
+    K/V, and no copy is as large as one lane's layer of K or V."""
+    cfg = get_config(SERVE_ARCH)
+    compiled, cache = _decode_for_one_chip(one_chip, cfg, width, 2048)
+    m = compiled.memory_analysis()
+    kv_width = cfg.num_kv_heads * cfg.head_dim
+    layer_kv = 2 * width * kv_width * 2048 * 2           # K and V, bf16
+    assert m.alias_size_in_bytes == _cache_bytes(cache)
+    assert m.temp_size_in_bytes < layer_kv
+    assert not _big_copies(compiled.as_text(), kv_width * 2048)
+
+
+@pytest.mark.parametrize("kind", DECODER_KINDS)
+def test_decode_cache_kinds_in_place(one_chip, kind):
+    """Every kind of decode cache, at a tiny registry config: the decode
+    step aliases the cache and holds less than one layer of it besides."""
+    compiled, cache = _decode_for_one_chip(
+        one_chip, get_smoke_config(DECODER_KINDS[kind]), 2, 2048)
+    m = compiled.memory_analysis()
+    layer = sum(a.size // a.shape[0] * a.dtype.itemsize
+                for a in jax.tree.leaves(cache["groups"]))
+    assert m.alias_size_in_bytes >= _cache_bytes(cache)
+    assert m.temp_size_in_bytes < layer
 
 
 def test_launcher_train_step_compiles_on_2x2(topo):

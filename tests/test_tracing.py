@@ -132,6 +132,7 @@ def test_engine_wave_counters_are_exact(engine, cluster):
     assert c["live_lane_steps"] == sum(budgets)
     assert c["lane_steps"] == len(budgets) * max(budgets)
     assert c["steps"] == max(budgets) - 1
+    assert c["donated_steps"] == c["steps"]
     assert 0 <= c["sync_s"] <= c["decode_s"]
     assert c["prefill_s"] > 0
     assert abs(w[0] + c["prefill_s"] + c["decode_s"] - c["end"]) < 1e-3
