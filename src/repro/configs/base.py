@@ -40,8 +40,34 @@ class MoEConfig:
     router_dtype: str = "float32"
     # dispatch implementation: "dense" (all experts, smoke tests),
     # "dropping" (GShard einsum dispatch, dry-run default),
-    # "ragged" (sort + lax.ragged_dot grouped GEMM, perf variant)
+    # "ragged" (sort + lax.ragged_dot grouped GEMM, dropless); serving
+    # (prefill and decode) always runs "ragged" or "dense", never drops
     dispatch: str = "dropping"
+    # router: experts in `n_group` groups, a group scored by its best
+    # expert, the top-k chosen among the `topk_group` best groups
+    # (DeepSeek's group_limited_greedy; 1/1 is a plain top-k); gates are
+    # the chosen scores renormalised to sum 1 (`norm_topk_prob`), else the
+    # scores times `routed_scaling_factor`
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the experts this chip holds under expert parallelism: ids
+    # first_expert .. first_expert + num_experts_held - 1 of num_experts
+    # (0 = all). The router scores all of them; chosen experts that are
+    # held elsewhere add nothing here.
+    num_experts_held: int = 0
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_experts_held or self.num_experts
+
+    def __post_init__(self):
+        assert self.num_experts % self.n_group == 0
+        assert self.topk_group <= self.n_group
+        assert 0 <= self.first_expert and \
+            self.first_expert + self.held <= self.num_experts
 
 
 @dataclass(frozen=True)
@@ -73,6 +99,21 @@ class MLAConfig:
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling as DeepSeek-V2 states it (`rope_scaling` of its
+    config.json, type "yarn"): frequencies past the correction range are
+    divided by `factor`, a linear ramp between; the softmax scale gains
+    mscale(factor, mscale_all_dim)^2 and cos/sin mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | audio | vlm
@@ -88,10 +129,12 @@ class ModelConfig:
     pattern: Tuple[str, ...] = (ATTN,)
     ffn_pattern: Tuple[str, ...] = (DENSE,)
     first_k_dense: int = 0           # leading layers forced to (pattern[0], DENSE)
+    dense_d_ff: int = 0              # their FFN width (0 -> d_ff or 4*d_model)
 
     # attention options
     rope_theta: float = 10_000.0
     partial_rotary_factor: float = 1.0
+    rope_scaling: Optional[YarnScaling] = None
     window_size: int = 0             # for SWA layers
     qk_norm: bool = False
     attn_logit_softcap: float = 0.0

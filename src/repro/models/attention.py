@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (apply_rope, dense_init, l2norm,
-                                 layer_read, layer_write)
+                                 layer_read, layer_write, rmsnorm,
+                                 yarn_softmax_factor)
 
 Params = Dict[str, Any]
 NEG_INF = -1e30
@@ -70,9 +71,11 @@ def _softmax_weights(s, q_pos, kv_pos, window: int, causal: bool,
 
 
 def naive_sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True,
-               softcap: float = 0.0) -> jnp.ndarray:
-    """q: (B,S,Kv,G,hd); k,v: (B,T,Kv,hd). Returns (B,S,Kv,G,hd)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+               softcap: float = 0.0, scale: Optional[float] = None
+               ) -> jnp.ndarray:
+    """q: (B,S,Kv,G,hd); k,v: (B,T,Kv,hd). Returns (B,S,Kv,G,hd). The
+    scores are scaled by `scale` (default 1/sqrt(hd))."""
+    scale = scale or 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bskgh,btkh->bkgst", q, k,
                    preferred_element_type=jnp.float32) * scale
     w = _softmax_weights(s, q_pos, kv_pos, window, causal, softcap, q.dtype)
@@ -94,10 +97,11 @@ def cached_sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0,
     return jnp.einsum("bkgst,bkht->bskgh", w, v)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def blockwise_sdpa(q, k, v, q_pos, kv_pos, window: int = 0,
                    causal: bool = True, softcap: float = 0.0,
-                   q_chunk: int = 1024, kv_chunk: int = 1024) -> jnp.ndarray:
+                   q_chunk: int = 1024, kv_chunk: int = 1024,
+                   scale: Optional[float] = None) -> jnp.ndarray:
     """Online-softmax attention, scanning KV chunks inside Q chunks, with a
     FlashAttention-style custom VJP: the forward saves only (out, lse); the
     backward recomputes each (q-chunk, kv-chunk) score block. Residual
@@ -106,12 +110,12 @@ def blockwise_sdpa(q, k, v, q_pos, kv_pos, window: int = 0,
     dominant train-memory term in the first dry-run sweep).
     """
     out, _ = _blockwise_fwd_impl(q, k, v, q_pos, kv_pos, window, causal,
-                                 softcap, q_chunk, kv_chunk)
+                                 softcap, q_chunk, kv_chunk, scale)
     return out
 
 
 def _blockwise_fwd_impl(q, k, v, q_pos, kv_pos, window, causal, softcap,
-                        q_chunk, kv_chunk):
+                        q_chunk, kv_chunk, scale):
     B, S, Kv, G, hd = q.shape
     T = k.shape[1]
     hd_v = v.shape[-1]           # may differ from hd (e.g. MLA nope+rope keys)
@@ -119,7 +123,7 @@ def _blockwise_fwd_impl(q, k, v, q_pos, kv_pos, window, causal, softcap,
     kv_chunk = min(kv_chunk, T)
     assert S % q_chunk == 0 and T % kv_chunk == 0, (S, q_chunk, T, kv_chunk)
     nq, nk = S // q_chunk, T // kv_chunk
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
 
     qb = q.reshape(B, nq, q_chunk, Kv, G, hd).swapaxes(0, 1)      # (nq,B,Cq,...)
     qp = q_pos.reshape(nq, q_chunk)
@@ -165,13 +169,14 @@ def _blockwise_fwd_impl(q, k, v, q_pos, kv_pos, window, causal, softcap,
 
 
 def _blockwise_fwd(q, k, v, q_pos, kv_pos, window, causal, softcap,
-                   q_chunk, kv_chunk):
+                   q_chunk, kv_chunk, scale):
     out, lse = _blockwise_fwd_impl(q, k, v, q_pos, kv_pos, window, causal,
-                                   softcap, q_chunk, kv_chunk)
+                                   softcap, q_chunk, kv_chunk, scale)
     return out, (q, k, v, q_pos, kv_pos, out, lse)
 
 
-def _blockwise_bwd(window, causal, softcap, q_chunk, kv_chunk, res, dout):
+def _blockwise_bwd(window, causal, softcap, q_chunk, kv_chunk, scale, res,
+                   dout):
     """FlashAttention-2-style backward: per (q-chunk, kv-chunk) block,
     recompute p from the saved lse, accumulate dq/dk/dv. Only O(chunk^2)
     transients; residuals are (q,k,v,out,lse)."""
@@ -182,7 +187,7 @@ def _blockwise_bwd(window, causal, softcap, q_chunk, kv_chunk, res, dout):
     qc = min(q_chunk, S)
     kc = min(kv_chunk, T)
     nq, nk = S // qc, T // kc
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
 
     # delta = rowsum(dout * out)  (B,Kv,G,S)
     delta = jnp.einsum("bskgh,bskgh->bkgs", dout.astype(jnp.float32),
@@ -245,13 +250,14 @@ blockwise_sdpa.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
 def sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True,
-         softcap: float = 0.0, blockwise_threshold: int = 2048):
+         softcap: float = 0.0, blockwise_threshold: int = 2048,
+         scale: Optional[float] = None):
     if q.shape[1] > blockwise_threshold:
         # nondiff args are positional (custom_vjp)
         return blockwise_sdpa(q, k, v, q_pos, kv_pos, window, causal,
-                              softcap)
+                              softcap, 1024, 1024, scale)
     return naive_sdpa(q, k, v, q_pos, kv_pos, window=window, causal=causal,
-                      softcap=softcap)
+                      softcap=softcap, scale=scale)
 
 
 # ============================================================ GQA forward
@@ -438,56 +444,79 @@ def mla_init(rng, cfg: ModelConfig) -> Params:
         "w_dkv": dense_init(ks[2], d, m.kv_lora_rank, dt),
         "kv_norm": {"scale": jnp.ones((m.kv_lora_rank,), dt)},
         "w_kr": dense_init(ks[3], d, m.rope_head_dim, dt),
-        # kept 3-D so the decode path can absorb them per-head
-        "w_uk": (jax.random.normal(ks[4], (m.kv_lora_rank, h, m.nope_head_dim),
+        # the latent's expansion, heads first as the published kv_b_proj
+        # lays it out: the decode path absorbs them head by head
+        "w_uk": (jax.random.normal(ks[4], (h, m.nope_head_dim, m.kv_lora_rank),
                                    jnp.float32) / math.sqrt(m.kv_lora_rank)).astype(dt),
-        "w_uv": (jax.random.normal(ks[5], (m.kv_lora_rank, h, m.v_head_dim),
+        "w_uv": (jax.random.normal(ks[5], (h, m.v_head_dim, m.kv_lora_rank),
                                    jnp.float32) / math.sqrt(m.kv_lora_rank)).astype(dt),
         "w_o": dense_init(ks[6], h * m.v_head_dim, d, dt),
     }
 
 
-def _mla_q(params, cfg, x, positions):
-    from repro.models.layers import rmsnorm
+def _mla_q(params, cfg, x, positions, *, pin_layout: bool = False):
     m = cfg.mla
     B, S, _ = x.shape
     h = cfg.num_heads
-    cq = rmsnorm(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["w_dq"]))
-    q = jnp.einsum("bsr,re->bse", cq, params["w_uq"]).reshape(
-        B, S, h, m.nope_head_dim + m.rope_head_dim)
+    cq = rmsnorm(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["w_dq"]),
+                 cfg.norm_eps)
+    q = jnp.einsum("bsr,re->bse", cq, params["w_uq"])
+    if pin_layout:
+        # Decode: left free, the split into nope and rope parts makes XLA
+        # re-lay a layer of w_uq out every step (75 MB at DeepSeek-V2)
+        q = jax.lax.optimization_barrier(q)
+    q = q.reshape(B, S, h, m.nope_head_dim + m.rope_head_dim)
     q_nope, q_rope = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
+                        scaling=cfg.rope_scaling)
     return q_nope, q_rope
 
 
 def _mla_ckv(params, cfg, x, positions):
-    from repro.models.layers import rmsnorm
-    c_kv = rmsnorm(params["kv_norm"], jnp.einsum("bsd,dr->bsr", x, params["w_dkv"]))
+    """The compressed K/V a token leaves in the cache: the normed latent
+    c_kv (B,S,kv_lora) and the rope key shared by all heads (B,S,rope)."""
+    c_kv = rmsnorm(params["kv_norm"],
+                   jnp.einsum("bsd,dr->bsr", x, params["w_dkv"]), cfg.norm_eps)
     k_rope = jnp.einsum("bsd,dr->bsr", x, params["w_kr"])            # (B,S,rope)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        scaling=cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_rope
 
 
-def mla_forward(params: Params, cfg: ModelConfig, x) -> jnp.ndarray:
-    """Unabsorbed (train/prefill) MLA: expand K/V per head, flash path."""
+def _mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: 1/sqrt(nope + rope), times YaRN's mscale^2."""
+    m = cfg.mla
+    return (yarn_softmax_factor(cfg.rope_scaling)
+            / math.sqrt(m.nope_head_dim + m.rope_head_dim))
+
+
+def _mla_attend(params: Params, cfg: ModelConfig, x, c_kv, k_rope,
+                positions) -> jnp.ndarray:
+    """Unabsorbed causal MLA of a sequence over itself: K/V expanded per
+    head from the latent, flash path for long sequences."""
     m = cfg.mla
     B, S, _ = x.shape
     h = cfg.num_heads
-    positions = jnp.arange(S)
     q_nope, q_rope = _mla_q(params, cfg, x, positions)
-    c_kv, k_rope = _mla_ckv(params, cfg, x, positions)
-    k_nope = jnp.einsum("bsr,rhe->bshe", c_kv, params["w_uk"])
-    v = jnp.einsum("bsr,rhe->bshe", c_kv, params["w_uv"])
+    k_nope = jnp.einsum("bsr,her->bshe", c_kv, params["w_uk"])
+    v = jnp.einsum("bsr,her->bshe", c_kv, params["w_uv"])
     q = jnp.concatenate([q_nope, q_rope], axis=-1)                  # (B,S,h,nope+rope)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (B, S, h, m.rope_head_dim))], axis=-1)
     # MLA has no KV grouping: treat each head as its own KV head (Kv=h, G=1)
-    out = sdpa(q[:, :, :, None, :].reshape(B, S, h, 1, -1), k, v,
-               positions, positions, causal=True)
+    out = sdpa(q[:, :, :, None, :], k, v, positions, positions, causal=True,
+               scale=_mla_scale(cfg))
     out = out.reshape(B, S, h * m.v_head_dim)
     return jnp.einsum("bse,ed->bsd", out, params["w_o"])
+
+
+def mla_forward(params: Params, cfg: ModelConfig, x) -> jnp.ndarray:
+    """Unabsorbed (train) MLA over the whole batch."""
+    with jax.named_scope("mla"):
+        positions = jnp.arange(x.shape[1])
+        c_kv, k_rope = _mla_ckv(params, cfg, x, positions)
+        return _mla_attend(params, cfg, x, c_kv, k_rope, positions)
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None):
@@ -501,18 +530,38 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype=None):
 
 
 def mla_prefill(params: Params, cfg: ModelConfig, x, *, max_seq: int = 0):
+    """Full-sequence MLA + the decode cache (the latent and rope key of
+    every position, padded to `max_seq`). Attention runs one lane at a
+    time, so a wave holds one lane's expanded K/V and score blocks (at
+    128 heads and 7k positions about 2 GB a lane) and not the wave's."""
     B, S, _ = x.shape
     max_seq = max_seq or S
-    out = mla_forward(params, cfg, x)
-    positions = jnp.arange(S)
-    c_kv, k_rope = _mla_ckv(params, cfg, x, positions)
-    cache = init_mla_cache(cfg, B, max_seq, dtype=c_kv.dtype)
-    cache = {
-        "c_kv": cache["c_kv"].at[:, :S].set(c_kv),
-        "k_rope": cache["k_rope"].at[:, :S].set(k_rope),
-        "slot_pos": cache["slot_pos"].at[:S].set(positions),
-    }
+    with jax.named_scope("mla"):
+        positions = jnp.arange(S)
+        c_kv, k_rope = _mla_ckv(params, cfg, x, positions)
+        out = jax.lax.map(
+            lambda a: _mla_attend(params, cfg, *(t[None] for t in a),
+                                  positions)[0], (x, c_kv, k_rope))
+        pad = ((0, 0), (0, max_seq - S), (0, 0))
+        cache = {
+            "c_kv": jnp.pad(c_kv, pad),
+            "k_rope": jnp.pad(k_rope, pad),
+            "slot_pos": jnp.pad(positions.astype(jnp.int32),
+                                (0, max_seq - S), constant_values=-1),
+        }
     return out, cache
+
+
+def _latent_attend(q_c, q_rope, c_kv, k_rope, bias, scale):
+    """One lane's absorbed attention: q_c (1,h,r) and q_rope (1,h,rope)
+    over the latent c_kv (T,r) and rope keys (T,rope) -> the latent
+    context (1,h,r)."""
+    s = (jnp.einsum("qhr,tr->hqt", q_c, c_kv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("qhe,te->hqt", q_rope, k_rope,
+                      preferred_element_type=jnp.float32))
+    w = jax.nn.softmax(s * scale + bias[None], axis=-1).astype(c_kv.dtype)
+    return jnp.einsum("hqt,tr->qhr", w, c_kv)
 
 
 def mla_decode(params: Params, cfg: ModelConfig, x, cache: Params, layer,
@@ -527,40 +576,41 @@ def mla_decode(params: Params, cfg: ModelConfig, x, cache: Params, layer,
     B = x.shape[0]
     h = cfg.num_heads
     pos1 = jnp.reshape(pos, (1,))
-    q_nope, q_rope = _mla_q(params, cfg, x, pos1)                  # (B,1,h,*)
-    c_kv_new, k_rope_new = _mla_ckv(params, cfg, x, pos1)
-    cache = {
-        "c_kv": layer_write(cache["c_kv"], layer, c_kv_new, (0, pos)),
-        "k_rope": layer_write(cache["k_rope"], layer, k_rope_new, (0, pos)),
-        "slot_pos": layer_write(cache["slot_pos"], layer,
-                                pos1.astype(jnp.int32), (pos,)),
-    }
-    c_kv, k_rope, slot_pos = (layer_read(cache[n], layer)
-                              for n in ("c_kv", "k_rope", "slot_pos"))
+    with jax.named_scope("mla"):
+        q_nope, q_rope = _mla_q(params, cfg, x, pos1,
+                                pin_layout=True)                   # (B,1,h,*)
+        c_kv_new, k_rope_new = _mla_ckv(params, cfg, x, pos1)
+        cache = {
+            "c_kv": layer_write(cache["c_kv"], layer, c_kv_new, (0, pos)),
+            "k_rope": layer_write(cache["k_rope"], layer, k_rope_new,
+                                  (0, pos)),
+            "slot_pos": layer_write(cache["slot_pos"], layer,
+                                    pos1.astype(jnp.int32), (pos,)),
+        }
+        c_kv, k_rope, slot_pos = (layer_read(cache[n], layer)
+                                  for n in ("c_kv", "k_rope", "slot_pos"))
 
-    if m.absorb_decode:
-        # q_c[b,h,r] = sum_e q_nope[b,h,e] W_uk[r,h,e]
-        q_c = jnp.einsum("bqhe,rhe->bqhr", q_nope, params["w_uk"])
-        s = (jnp.einsum("bqhr,btr->bhqt", q_c, c_kv,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bqhe,bte->bhqt", q_rope, k_rope,
-                          preferred_element_type=jnp.float32))
-        s = s / math.sqrt(m.nope_head_dim + m.rope_head_dim)
-        s = s + _mask_bias(pos1, slot_pos, 0, True)[None, None]
-        w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        ctx_c = jnp.einsum("bhqt,btr->bqhr", w, c_kv)              # latent ctx
-        out = jnp.einsum("bqhr,rhe->bqhe", ctx_c, params["w_uv"])
-    else:
-        k_nope = jnp.einsum("btr,rhe->bthe", c_kv, params["w_uk"])
-        v = jnp.einsum("btr,rhe->bthe", c_kv, params["w_uv"])
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                      k_nope.shape[:3] + (m.rope_head_dim,))],
-            axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        out = naive_sdpa(q[:, :, :, None, :], k, v, pos1, slot_pos,
-                         causal=True)
-        out = out.reshape(B, 1, h, m.v_head_dim)
-    out = out.reshape(B, 1, h * m.v_head_dim)
-    out = jnp.einsum("bse,ed->bsd", out, params["w_o"])
+        if m.absorb_decode:
+            # q_c[b,h,r] = sum_e q_nope[b,h,e] W_uk[h,e,r]
+            q_c = jnp.einsum("bqhe,her->bqhr", q_nope, params["w_uk"])
+            bias = _mask_bias(pos1, slot_pos, 0, True)               # (1,T)
+            # One lane at a time: batched over lanes, XLA lays the cache
+            # out positions before lanes and copies it whole every step
+            ctx_c = jax.lax.map(
+                lambda a: _latent_attend(*a, bias, _mla_scale(cfg)),
+                (q_c, q_rope, c_kv, k_rope))                       # (B,1,h,r)
+            out = jnp.einsum("bqhr,her->bqhe", ctx_c, params["w_uv"])
+        else:
+            k_nope = jnp.einsum("btr,her->bthe", c_kv, params["w_uk"])
+            v = jnp.einsum("btr,her->bthe", c_kv, params["w_uv"])
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                          k_nope.shape[:3]
+                                          + (m.rope_head_dim,))], axis=-1)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            out = naive_sdpa(q[:, :, :, None, :], k, v, pos1, slot_pos,
+                             causal=True, scale=_mla_scale(cfg))
+            out = out.reshape(B, 1, h, m.v_head_dim)
+        out = out.reshape(B, 1, h * m.v_head_dim)
+        out = jnp.einsum("bse,ed->bsd", out, params["w_o"])
     return out, cache

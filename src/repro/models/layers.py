@@ -60,25 +60,64 @@ def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 
 # ---------------------------------------------------------------- RoPE
 
-def rope_frequencies(head_dim: int, theta: float, rotary_dim: Optional[int] = None):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched by `factor`."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(scaling, rotary_dim: int, theta: float):
+    """(low, high) rotary pair indices between which YaRN ramps from the
+    original frequencies to the interpolated ones."""
+    def pair(rotations):
+        return rotary_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(pair(scaling.beta_fast)), 0),
+            min(math.ceil(pair(scaling.beta_slow)), rotary_dim - 1))
+
+
+def yarn_softmax_factor(scaling) -> float:
+    """What YaRN multiplies the attention softmax scale by (1 without)."""
+    if scaling is None or not scaling.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def rope_frequencies(head_dim: int, theta: float, rotary_dim: Optional[int] = None,
+                     scaling=None):
+    """(rotary_dim/2,) inverse frequencies; with `scaling` (a
+    `YarnScaling`), YaRN's: pairs past the correction range divided by
+    the factor, a linear ramp across it."""
     rotary_dim = rotary_dim or head_dim
     assert rotary_dim % 2 == 0
     exponents = jnp.arange(0, rotary_dim, 2, dtype=jnp.float32) / rotary_dim
-    return 1.0 / (theta ** exponents)  # (rotary_dim/2,)
+    inv_freq = 1.0 / (theta ** exponents)  # (rotary_dim/2,)
+    if scaling is None:
+        return inv_freq
+    low, high = yarn_correction_range(scaling, rotary_dim, theta)
+    ramp = jnp.clip((jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inv_freq / scaling.factor * ramp + inv_freq * (1.0 - ramp)
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               rotary_fraction: float = 1.0) -> jnp.ndarray:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+               rotary_fraction: float = 1.0, scaling=None) -> jnp.ndarray:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S). `scaling`
+    (a `YarnScaling`) changes the frequencies and the cos/sin magnitude."""
     hd = x.shape[-1]
     rot = int(hd * rotary_fraction)
     rot -= rot % 2
     if rot == 0:
         return x
-    inv_freq = rope_frequencies(hd, theta, rot)                    # (rot/2,)
+    inv_freq = rope_frequencies(hd, theta, rot, scaling)           # (rot/2,)
     angles = positions[..., :, None].astype(jnp.float32) * inv_freq  # (..., S, rot/2)
     sin = jnp.sin(angles)[..., :, None, :]                          # (..., S, 1, rot/2)
     cos = jnp.cos(angles)[..., :, None, :]
+    if scaling is not None:
+        mag = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if mag != 1.0:
+            sin, cos = sin * mag, cos * mag
     x_rot, x_pass = x[..., :rot], x[..., rot:]
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     y1 = x1 * cos - x2 * sin

@@ -74,20 +74,14 @@ def _init_layer(rng, cfg: ModelConfig, kind: str, ffn_kind: str,
     return p
 
 
-def _dense_ffn_width(cfg: ModelConfig) -> int:
-    # deepseek-style: dense first-layer FFN is wider than per-expert width
-    if cfg.moe is not None and cfg.d_ff < cfg.d_model:
-        return 2 * cfg.d_model  # dense stand-in width (MXU-aligned)
-    return cfg.d_ff or 4 * cfg.d_model
-
-
 def _init_first_layer(rng, cfg: ModelConfig, with_cross: bool) -> Params:
-    """first_k_dense layers: pattern[0] mixer + dense FFN of _dense_ffn_width."""
+    """first_k_dense layers: pattern[0] mixer + dense FFN of `dense_d_ff`."""
     ks = jax.random.split(rng, 3)
     dt = jnp.dtype(cfg.param_dtype)
     p = _init_layer(ks[0], cfg, cfg.pattern[0], NONE, with_cross)
     p["post_norm"] = rmsnorm_init(cfg.d_model, dt)
-    p["ffn"] = swiglu_init(ks[1], cfg.d_model, _dense_ffn_width(cfg), dt)
+    p["ffn"] = swiglu_init(ks[1], cfg.d_model,
+                           cfg.dense_d_ff or cfg.d_ff or 4 * cfg.d_model, dt)
     return p
 
 
@@ -193,15 +187,43 @@ class Model:
             h = self._act(h + attn.cross_attention_forward(lp["cross"], cfg,
                                                            c_in, kv))
         if "ffn" in lp and ffn_kind != NONE:
-            f_in = rmsnorm(lp["post_norm"], h, cfg.norm_eps)
-            if ffn_kind == MOE and "router" in lp["ffn"]:
-                y, moe_aux = moe_lib.moe_apply(lp["ffn"], cfg, f_in,
-                                               self._moe_shard())
-                aux = {k: aux[k] + moe_aux[k] for k in aux}
-            else:
-                y = swiglu(lp["ffn"], f_in)
+            y, moe_aux = self._ffn(lp, ffn_kind, h)
+            aux = {k: aux[k] + moe_aux[k] for k in aux} if moe_aux else aux
             h = self._act(h + y)
         return h, aux
+
+    def _ffn(self, lp: Params, ffn_kind: str, h, dropless=False,
+             experts=None, layer=None):
+        """The layer's FFN on its normed input, and the MoE layer's aux
+        (`moe.moe_apply`; none for a dense FFN): the router's losses in
+        training, the routing counts when `dropless` (serving).
+        `experts`: the routed experts' weights stacked over layers, of
+        which this is `layer` (`lp` then lacks them)."""
+        f_in = rmsnorm(lp["post_norm"], h, self.cfg.norm_eps)
+        if ffn_kind == MOE and "router" in lp["ffn"]:
+            return moe_lib.moe_apply(
+                dict(lp["ffn"], **(experts or {})), self.cfg, f_in,
+                self._moe_shard(), dropless, layer if experts else None)
+        return swiglu(lp["ffn"], f_in), {}
+
+    def _unstack_experts(self, groups):
+        """The scanned groups' parameters without the routed experts'
+        weights, and those weights whole (stacked over the layers, None
+        where a pattern position has none): the decode step's grouped
+        product reads its layer's experts where they lie in the stack.
+        The dense dispatch takes its layer's slice as the scan gives it."""
+        xs, whole = [], []
+        for g in groups:
+            f = g.get("ffn", {})
+            if "router" not in f or self.cfg.moe.dispatch == "dense":
+                xs.append(g)
+                whole.append(None)
+                continue
+            xs.append(dict(g, ffn={k: v for k, v in f.items()
+                                   if k not in moe_lib.EXPERT_WEIGHTS}))
+            whole.append({k: f[k] for k in moe_lib.EXPERT_WEIGHTS})
+        return tuple(xs), whole
+
 
     def _remat(self, fn):
         pol = self.cfg.remat_policy
@@ -398,8 +420,9 @@ class Model:
 
         def group_cache(_):
             return tuple(
-                self._init_layer_cache(k, batch, max_seq, with_cross)
-                for k in cfg.pattern)
+                dict(self._init_layer_cache(k, batch, max_seq, with_cross),
+                     **(_slots_cache({}) if f == MOE else {}))
+                for k, f in zip(cfg.pattern, cfg.ffn_pattern))
 
         cache: Params = {
             "groups": jax.vmap(group_cache)(jnp.arange(cfg.num_groups))}
@@ -413,13 +436,15 @@ class Model:
     # ------------------------------------------------------------- decode
 
     def _layer_decode(self, lp: Params, kind: str, ffn_kind: str, h, cache,
-                      layer, pos):
+                      layer, pos, experts=None):
         """One layer's step. `cache`: this pattern position's leaves,
         stacked over layers; the layer writes what it changed at `layer`
-        and returns the updated stack."""
+        and returns the updated stack (an expert layer adds its routing
+        counts to its own)."""
         cfg = self.cfg
         mix_in = rmsnorm(lp["pre_norm"], h, cfg.norm_eps)
-        core = {k: v for k, v in cache.items() if not k.startswith("cross_")}
+        core = {k: v for k, v in cache.items()
+                if not k.startswith("cross_") and k not in SLOTS}
         if kind in (ATTN, SWA):
             window = cfg.window_size if kind == SWA else 0
             out, core = attn.attention_decode(lp["mixer"], cfg, mix_in, core,
@@ -443,13 +468,13 @@ class Model:
             h = h + attn.cross_attention_forward(lp["cross"], cfg, c_in,
                                                  enc_kv)
         if "ffn" in lp and ffn_kind != NONE:
-            f_in = rmsnorm(lp["post_norm"], h, cfg.norm_eps)
-            if ffn_kind == MOE and "router" in lp["ffn"]:
-                y, _ = moe_lib.moe_apply(lp["ffn"], cfg, f_in,
-                                         self._moe_shard())
-            else:
-                y = swiglu(lp["ffn"], f_in)
+            y, counts = self._ffn(lp, ffn_kind, h, dropless=True,
+                                  experts=experts, layer=layer)
             h = h + y
+            if ffn_kind == MOE:
+                core.update({k: layer_write(cache[k], layer, counts[k]
+                                            + layer_read(cache[k], layer))
+                             for k in ("routed_slots", "held_slots")})
         return h, dict(cache, **core)
 
     def decode_step(self, params: Params, cache: Params, tokens, pos):
@@ -462,7 +487,8 @@ class Model:
         through the layer scan; each layer writes only its new state at its
         own index. Attention's K and V are (L, B, Kv*hd, cap), heads x
         head-dim before positions, one column written per step; MLA's
-        c_kv/k_rope (L, B, cap, r); recurrent states whole per layer."""
+        c_kv/k_rope (L, B, cap, r); recurrent states whole per layer; an
+        expert layer's routing counts (`SLOTS`), one each per layer."""
         cfg = self.cfg
         h = embed_tokens(params["embed"], tokens)
         new_cache: Params = {}
@@ -475,6 +501,8 @@ class Model:
                 new_first.append(jax.tree.map(lambda a: a[0], c))
             new_cache["first"] = new_first
 
+        groups, experts = self._unstack_experts(params["groups"])
+
         def group_body(carry, xs):
             hh, g_cache = carry
             g_params, layer = xs
@@ -482,12 +510,12 @@ class Model:
             for i, kind in enumerate(cfg.pattern):
                 hh, g_cache[i] = self._layer_decode(
                     g_params[i], kind, cfg.ffn_pattern[i], hh, g_cache[i],
-                    layer, pos)
+                    layer, pos, experts[i])
             return (hh, tuple(g_cache)), None
 
         (h, new_cache["groups"]), _ = jax.lax.scan(
             group_body, (h, cache["groups"]),
-            (params["groups"], jnp.arange(cfg.num_groups, dtype=jnp.int32)))
+            (groups, jnp.arange(cfg.num_groups, dtype=jnp.int32)))
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
         logits = unembed(params["embed"], h, cfg.tie_embeddings,
                          params.get("lm_head"))
@@ -500,36 +528,32 @@ class Model:
         cfg = self.cfg
         h, enc_out = self._embed_inputs(params, batch)
         max_seq = max_seq or h.shape[1]
-        aux = {"moe_lb_loss": jnp.zeros((), jnp.float32),
-               "moe_z_loss": jnp.zeros((), jnp.float32)}
         new_cache: Params = {}
         if cfg.first_k_dense:
             firsts = []
             for lp in params["first"]:
-                h, aux, c = self._layer_prefill(lp, cfg.pattern[0], DENSE, h,
-                                                aux, enc_out, max_seq)
+                h, c = self._layer_prefill(lp, cfg.pattern[0], DENSE, h,
+                                           enc_out, max_seq)
                 firsts.append(c)
             new_cache["first"] = firsts
 
-        def group_body(carry, g_params):
-            hh, aux_c = carry
+        def group_body(hh, g_params):
             caches = []
             for i, kind in enumerate(cfg.pattern):
-                hh, aux_c, c = self._layer_prefill(
-                    g_params[i], kind, cfg.ffn_pattern[i], hh, aux_c,
-                    enc_out, max_seq)
+                hh, c = self._layer_prefill(
+                    g_params[i], kind, cfg.ffn_pattern[i], hh, enc_out,
+                    max_seq)
                 caches.append(c)
-            return (hh, aux_c), tuple(caches)
+            return hh, tuple(caches)
 
-        (h, aux), groups_cache = jax.lax.scan(group_body, (h, aux),
-                                              params["groups"])
+        h, groups_cache = jax.lax.scan(group_body, h, params["groups"])
         new_cache["groups"] = groups_cache
         h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
         logits = unembed(params["embed"], h[:, -1:], cfg.tie_embeddings,
                          params.get("lm_head"))
         return logits, new_cache
 
-    def _layer_prefill(self, lp, kind, ffn_kind, h, aux, enc_out, max_seq):
+    def _layer_prefill(self, lp, kind, ffn_kind, h, enc_out, max_seq):
         cfg = self.cfg
         mix_in = rmsnorm(lp["pre_norm"], h, cfg.norm_eps)
         if kind in (ATTN, SWA):
@@ -570,15 +594,11 @@ class Model:
             core = dict(core, cross_k=kv["k"][:, :max_seq],
                         cross_v=kv["v"][:, :max_seq])
         if "ffn" in lp and ffn_kind != NONE:
-            f_in = rmsnorm(lp["post_norm"], h, cfg.norm_eps)
-            if ffn_kind == MOE and "router" in lp["ffn"]:
-                y, moe_aux = moe_lib.moe_apply(lp["ffn"], cfg, f_in,
-                                               self._moe_shard())
-                aux = {k: aux[k] + moe_aux[k] for k in aux}
-            else:
-                y = swiglu(lp["ffn"], f_in)
+            y, counts = self._ffn(lp, ffn_kind, h, dropless=True)
             h = self._act(h + y)
-        return h, aux, core
+            if ffn_kind == MOE:
+                core = dict(core, **_slots_cache(counts))
+        return h, core
 
     # -------------------------------------------------------------- specs
 
@@ -601,6 +621,32 @@ class Model:
             return {"tokens": jax.ShapeDtypeStruct((b, s), i32)}
         # decode: one new token against a cache of length seq_len
         return {"tokens": jax.ShapeDtypeStruct((b, 1), i32)}
+
+
+#: an expert layer's routing counts in the decode cache, one each per
+#: layer: its prefill's token x top-k slots and those that chose an
+#: expert held here, and the same summed over the decode steps since
+SLOTS = ("prefill_routed_slots", "prefill_held_slots", "routed_slots",
+         "held_slots")
+
+
+def _slots_cache(prefill_counts) -> Params:
+    """An expert layer's cache counters, from its prefill's counts (zero
+    where none are given): the decode steps' start at zero."""
+    zero = jnp.zeros((), jnp.int32)
+    return {"prefill_routed_slots": prefill_counts.get("routed_slots", zero),
+            "prefill_held_slots": prefill_counts.get("held_slots", zero),
+            "routed_slots": zero, "held_slots": zero}
+
+
+def routing_counts(cache) -> Dict[str, int]:
+    """The routing counts a decode cache holds (`SLOTS`), summed over its
+    expert layers, read from the device at once; empty for a model
+    without expert layers."""
+    per = jax.device_get([{k: c[k] for k in SLOTS}
+                          for c in cache.get("groups", ()) if SLOTS[0] in c])
+    return {k: int(sum(c[k].sum() for c in per)) for k in SLOTS} if per \
+        else {}
 
 
 def build_model(cfg: ModelConfig, rules=None) -> Model:

@@ -122,8 +122,8 @@ class ShardingRules:
             return out(tp, fsdp)
         if name == "lm_head":
             return out(fsdp, tp)
-        if name in ("w_uk", "w_uv"):             # (r, H, e) MLA per-head
-            return out(None, tp, None)
+        if name in ("w_uk", "w_uv"):             # (H, e, r) MLA per-head
+            return out(tp, None, None)
         if nd == 3 and name in ("w_gate", "w_up", "w_down"):
             e = base_shape[0]
             if e % mesh.shape[tp] == 0:          # expert parallel
@@ -235,7 +235,7 @@ class ShardingRules:
             spec = _fit(mesh, axes, base)
             return P(None, *spec) if stacked else spec
 
-        if name == "slot_pos":
+        if name == "slot_pos" or name.endswith("_slots"):   # + counters
             return out(*([None] * len(base)))
         if name in ("k", "v"):                         # (B, kv*hd, cap)
             if self.cfg.num_kv_heads % mesh.shape[tp] == 0:

@@ -38,7 +38,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core import profiler
-from repro.models.model import Model
+from repro.models.model import Model, routing_counts
 
 
 @dataclass
@@ -104,7 +104,12 @@ class ServingEngine:
         `donated_steps` (those after which the cache passed in was
         deleted: the runtime took the donation), `lane_steps` (width x
         loop iterations) and `live_lane_steps` (lanes still under budget,
-        summed over the iterations)."""
+        summed over the iterations). A model with expert layers adds the
+        routing counts its programs kept in the cache, read once at the
+        wave's end: `routed_slots` (token x top-k slots over the fed lanes
+        and expert layers of the decode steps), `held_slots` (those that
+        chose an expert held here), and `prefill_routed_slots` and
+        `prefill_held_slots` for the prefill."""
         prompts = np.stack([r.prompt for r in wave])        # equal lengths
         b, s = prompts.shape
         budgets = np.array([r.max_new_tokens for r in wave])
@@ -145,6 +150,7 @@ class ServingEngine:
             sp.set(steps=steps, donated_steps=donated, lane_steps=b * iters,
                    live_lane_steps=int(np.minimum(budgets, iters).sum()),
                    prefill_s=t1 - t0, decode_s=now - t1, sync_s=sync_s)
+            sp.set(**routing_counts(cache))
         return [Response(r.request_id, o, now - r.created)
                 for r, o in zip(wave, outs)]
 

@@ -7,6 +7,7 @@ until it exits. So the topology is described inside a module fixture of
 this one file, never while a module is imported: every xdist worker then
 collects the same tests, and only the worker given this file loads it.
 """
+import dataclasses
 import math
 import re
 from functools import partial
@@ -141,6 +142,40 @@ def test_decode_cache_kinds_in_place(one_chip, kind):
                 for a in jax.tree.leaves(cache["groups"]))
     assert m.alias_size_in_bytes >= _cache_bytes(cache)
     assert m.temp_size_in_bytes < layer
+
+
+def _deepseek_cell():
+    """deepseek-v2-236b as the benchmark cell cuts it: 6 layers, an eighth
+    of the vocabulary, experts 0-7 of 160 held; every width published."""
+    base = get_config("deepseek-v2-236b")
+    return base.scaled(num_layers=6, vocab_size=12_800,
+                       moe=dataclasses.replace(base.moe, num_experts_held=8))
+
+
+def test_deepseek_prefill_of_a_full_wave_fits_one_chip(one_chip):
+    """A wave of 8 prompts of 7,168 tokens into a cache of 8,192: the
+    prefill's temporaries (one lane's expanded K/V and score blocks, one
+    lane's sorted expert rows) fit beside the 4.8 GB of weights."""
+    model = build_model(_deepseek_cell())
+    params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    eng = ServingEngine(model, params, max_seq=8192)
+    tokens = jax.ShapeDtypeStruct((8, 7168), jnp.int32, sharding=one_chip)
+    compiled = eng._prefill.lower(params, {"tokens": tokens}).compile()
+    assert _device_bytes(compiled) < 10e9 < HBM_BYTES
+
+
+def test_deepseek_decode_updates_latent_cache_in_place(one_chip):
+    """The decode step at width 8 over 8,192 positions: the latent cache
+    (c_kv, k_rope) is aliased whole, nothing as large as one lane's layer
+    of it is copied, and the temporaries stay under 10 MB (no layer of
+    cache or expert weights is copied out)."""
+    cfg = _deepseek_cell()
+    compiled, cache = _decode_for_one_chip(one_chip, cfg, 8, 8192)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= _cache_bytes(cache)
+    assert m.temp_size_in_bytes < 10e6
+    assert not _big_copies(compiled.as_text(), 8192 * cfg.mla.kv_lora_rank)
+    assert _device_bytes(compiled) < HBM_BYTES
 
 
 def test_launcher_train_step_compiles_on_2x2(topo):
