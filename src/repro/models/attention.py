@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (apply_rope, dense_init, l2norm,
@@ -28,6 +29,10 @@ from repro.models.layers import (apply_rope, dense_init, l2norm,
 
 Params = Dict[str, Any]
 NEG_INF = -1e30
+#: `sdpa` runs the blockwise form for more queries than this, in chunks
+#: of `CHUNK` queries and keys
+BLOCKWISE_THRESHOLD = 2048
+CHUNK = 1024
 
 
 # =============================================================== GQA params
@@ -59,6 +64,25 @@ def _mask_bias(q_pos, kv_pos, window: int, causal: bool):
     if window > 0:
         valid &= (q_pos[:, None] - kv_pos[None, :]) < window
     return jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
+
+
+def block_reached(q_pos, kv_pos, window: int, causal: bool):
+    """Whether a block of queries at `q_pos` (..., Cq) and keys at `kv_pos`
+    (..., Ck) may hold a pair that `_mask_bias` keeps: some key is valid,
+    the lowest valid key is not after the last query (causal) and the
+    highest key is within `window` of the first query. Read from the
+    bounds of the positions, so it is never False where a pair is kept.
+    The leading axes broadcast; numpy positions give numpy booleans,
+    traced ones traced."""
+    xp = jnp if isinstance(kv_pos, jax.Array) else np
+    valid = kv_pos >= 0
+    reached = valid.any(-1)
+    if causal:
+        lowest = xp.where(valid, kv_pos, xp.iinfo(kv_pos.dtype).max)
+        reached = reached & (lowest.min(-1) <= q_pos.max(-1))
+    if window > 0:
+        reached = reached & (kv_pos.max(-1) > q_pos.min(-1) - window)
+    return reached
 
 
 def _softmax_weights(s, q_pos, kv_pos, window: int, causal: bool,
@@ -132,21 +156,30 @@ def _blockwise_fwd_impl(q, k, v, q_pos, kv_pos, window, causal, softcap,
     kp = kv_pos.reshape(nk, kv_chunk)
 
     def kv_body(carry, blk):
-        m, l, acc = carry
-        q_i, qp_i, k_j, v_j, kp_j = blk
-        s = jnp.einsum("bckgh,btkh->bkgct", q_i, k_j,
-                       preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = jnp.tanh(s / softcap) * softcap
-        s = s + _mask_bias(qp_i, kp_j, window, causal)[None, None, None]
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l = l * corr + p.sum(axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "bkgct,btkh->bkgch", p.astype(q_i.dtype), v_j,
-            preferred_element_type=jnp.float32)
-        return (m_new, l, acc), None
+        # a block with no pair the mask keeps is skipped: (m, l, acc) stay
+        # as they are, where computing it would add weights of 0 to them
+        # (or, before any kept key, values the next kept key zeroes)
+        q_i, qp_i, j, kp_j = blk
+
+        def attend(carry):
+            m, l, acc = carry
+            k_j, v_j = kb[j], vb[j]
+            s = jnp.einsum("bckgh,btkh->bkgct", q_i, k_j,
+                           preferred_element_type=jnp.float32) * scale
+            if softcap:
+                s = jnp.tanh(s / softcap) * softcap
+            s = s + _mask_bias(qp_i, kp_j, window, causal)[None, None, None]
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(axis=-1)
+            acc = acc * corr[..., None] + jnp.einsum(
+                "bkgct,btkh->bkgch", p.astype(q_i.dtype), v_j,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        return jax.lax.cond(block_reached(qp_i, kp_j, window, causal),
+                            attend, lambda c: c, carry), None
 
     def q_body(blk):
         q_i, qp_i = blk
@@ -157,7 +190,8 @@ def _blockwise_fwd_impl(q, k, v, q_pos, kv_pos, window, causal, softcap,
         def scan_fn(carry, j_blk):
             return kv_body(carry, (q_i, qp_i) + j_blk)
 
-        (m, l, acc), _ = jax.lax.scan(scan_fn, (m0, l0, a0), (kb, vb, kp))
+        (m, l, acc), _ = jax.lax.scan(scan_fn, (m0, l0, a0),
+                                      (jnp.arange(nk), kp))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         lse = m + jnp.log(jnp.maximum(l, 1e-30))       # (B,Kv,G,Cq)
         return out.astype(q.dtype), lse
@@ -250,14 +284,28 @@ blockwise_sdpa.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
 def sdpa(q, k, v, q_pos, kv_pos, *, window: int = 0, causal: bool = True,
-         softcap: float = 0.0, blockwise_threshold: int = 2048,
-         scale: Optional[float] = None):
-    if q.shape[1] > blockwise_threshold:
+         softcap: float = 0.0, scale: Optional[float] = None):
+    if q.shape[1] > BLOCKWISE_THRESHOLD:
         # nondiff args are positional (custom_vjp)
         return blockwise_sdpa(q, k, v, q_pos, kv_pos, window, causal,
-                              softcap, 1024, 1024, scale)
+                              softcap, CHUNK, CHUNK, scale)
     return naive_sdpa(q, k, v, q_pos, kv_pos, window=window, causal=causal,
                       softcap=softcap, scale=scale)
+
+
+def sdpa_blocks(q_pos: np.ndarray, kv_pos: np.ndarray, *, window: int = 0,
+                causal: bool = True) -> Tuple[int, int]:
+    """The (query chunk, KV chunk) blocks of `sdpa` over these positions,
+    and how many of them its blockwise form computes (`block_reached`):
+    (0, 0) where it takes the naive path."""
+    S, T = len(q_pos), len(kv_pos)
+    if S <= BLOCKWISE_THRESHOLD:
+        return 0, 0
+    cq, ck = min(CHUNK, S), min(CHUNK, T)
+    nq, nk = S // cq, T // ck
+    reached = block_reached(q_pos.reshape(nq, 1, cq),
+                            kv_pos.reshape(1, nk, ck), window, causal)
+    return nq * nk, int(np.broadcast_to(reached, (nq, nk)).sum())
 
 
 # ============================================================ GQA forward

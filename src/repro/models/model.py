@@ -19,10 +19,12 @@ from __future__ import annotations
 
 import functools
 import math
+from collections import Counter
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import (ATTN, DENSE, MAMBA, MLA, MLSTM, MOE, NONE,
                                 SLSTM, SWA, ModelConfig, ShapeConfig)
@@ -552,6 +554,25 @@ class Model:
         logits = unembed(params["embed"], h[:, -1:], cfg.tie_embeddings,
                          params.get("lm_head"))
         return logits, new_cache
+
+    def prefill_attention_blocks(self, prompt_len: int) -> Tuple[int, int]:
+        """The blocks of blockwise attention that one lane's prefill of
+        `prompt_len` tokens forms, and how many of them it computes, summed
+        over the self-attention layers (`attn.sdpa_blocks`): (0, 0) where
+        every layer takes the naive path. An encoder's attention and
+        cross-attention, which depend on the encoder's input, are not
+        counted."""
+        cfg = self.cfg
+        pos = np.arange(prompt_len)
+        kinds = Counter([cfg.pattern[0]] * cfg.first_k_dense
+                        + list(cfg.pattern) * cfg.num_groups)
+        blocks = run = 0
+        for kind, layers in kinds.items():
+            if kind in (ATTN, SWA, MLA):
+                window = cfg.window_size if kind == SWA else 0
+                b, r = attn.sdpa_blocks(pos, pos, window=window)
+                blocks, run = blocks + layers * b, run + layers * r
+        return blocks, run
 
     def _layer_prefill(self, lp, kind, ffn_kind, h, enc_out, max_seq):
         cfg = self.cfg

@@ -109,7 +109,10 @@ class ServingEngine:
         wave's end: `routed_slots` (token x top-k slots over the fed lanes
         and expert layers of the decode steps), `held_slots` (those that
         chose an expert held here), and `prefill_routed_slots` and
-        `prefill_held_slots` for the prefill."""
+        `prefill_held_slots` for the prefill. `prefill_attn_blocks` and
+        `prefill_attn_blocks_run` count the blocks of blockwise attention
+        the prefill forms and computes, over its lanes and layers (0
+        where no layer's attention is blockwise)."""
         prompts = np.stack([r.prompt for r in wave])        # equal lengths
         b, s = prompts.shape
         budgets = np.array([r.max_new_tokens for r in wave])
@@ -150,7 +153,9 @@ class ServingEngine:
             sp.set(steps=steps, donated_steps=donated, lane_steps=b * iters,
                    live_lane_steps=int(np.minimum(budgets, iters).sum()),
                    prefill_s=t1 - t0, decode_s=now - t1, sync_s=sync_s)
-            sp.set(**routing_counts(cache))
+            blocks, run = self.model.prefill_attention_blocks(s)
+            sp.set(prefill_attn_blocks=b * blocks,
+                   prefill_attn_blocks_run=b * run, **routing_counts(cache))
         return [Response(r.request_id, o, now - r.created)
                 for r, o in zip(wave, outs)]
 

@@ -214,9 +214,10 @@ def cluster():
     core.shutdown()
 
 
-def _wave(cluster, cfg, budgets, plen=8):
+def _wave(cluster, cfg, budgets, plen=8, max_seq=32):
     model = build_model(cfg)
-    eng = ServingEngine(model, model.init(jax.random.PRNGKey(0)), max_seq=32)
+    eng = ServingEngine(model, model.init(jax.random.PRNGKey(0)),
+                        max_seq=max_seq)
     rng = np.random.default_rng(0)
     eng.serve([Request(i, rng.integers(1, 200, plen).astype(np.int32), b)
                for i, b in enumerate(budgets)], max_wave=len(budgets))
@@ -245,6 +246,28 @@ def test_a_model_without_experts_counts_none(cluster):
     c = _wave(cluster, get_smoke_config("stablelm-1.6b"), [3, 2])
     assert not {"routed_slots", "held_slots", "prefill_routed_slots",
                 "prefill_held_slots"} & set(c)
+    assert c["prefill_attn_blocks"] == c["prefill_attn_blocks_run"] == 0
+
+
+def test_prefill_attention_blocks_of_the_cell_and_of_short_prompts():
+    """The cell's 6 layers over 7,168 tokens: 7 x 7 blocks a layer, of
+    which the 7 diagonal blocks and the 21 below them are computed; at
+    512 tokens attention takes the naive path and forms no block."""
+    base = get_config("deepseek-v2-236b")
+    cell = base.scaled(num_layers=6, vocab_size=12_800,
+                       moe=dataclasses.replace(base.moe, num_experts_held=8))
+    assert build_model(cell).prefill_attention_blocks(7168) == (294, 168)
+    stablelm = build_model(get_config("stablelm-1.6b"))
+    assert stablelm.prefill_attention_blocks(512) == (0, 0)
+
+
+def test_engine_wave_counts_prefill_attention_blocks(cluster):
+    """A wave of 2 prompts of 3,072 tokens: 3 x 3 blocks a lane and
+    layer, 6 of them computed."""
+    cfg = get_smoke_config("deepseek-v2-236b")
+    c = _wave(cluster, cfg, [2, 1], plen=3072, max_seq=3076)
+    assert (c["prefill_attn_blocks"], c["prefill_attn_blocks_run"]) == (
+        2 * cfg.num_layers * 9, 2 * cfg.num_layers * 6)
 
 
 def test_registry_entry_states_the_published_model():

@@ -203,6 +203,72 @@ def test_blockwise_attention_grads_match_naive():
                                    atol=2e-4)
 
 
+#: `sdpa` at 256 queries in chunks of 32 (8 x 8 blocks, or 8 x 4 over 128
+#: encoder keys): the blocks computed, of those formed
+BLOCK_CASES = {
+    # the causal triangle: 8 diagonal blocks and the 28 below them
+    "causal": dict(T=256, hd=16, hd_v=16, window=0, causal=True, run=36),
+    # queries one position ahead of the keys: each query chunk also
+    # reaches the next key chunk, whose first key only its last query sees
+    "offset": dict(T=256, hd=16, hd_v=16, window=0, causal=True, run=43,
+                   q_offset=1),
+    # keys from 216 on invalid (kv_pos -1): the last key chunk, wholly
+    # invalid, is never computed
+    "padded": dict(T=256, hd=16, hd_v=16, window=0, causal=True, run=35,
+                   kv_valid=216),
+    # keys within 48 of the query: each query chunk reaches its own and
+    # the two before it
+    "window": dict(T=256, hd=16, hd_v=16, window=48, causal=True, run=21),
+    # cross-attention: every query sees every key
+    "cross": dict(T=128, hd=16, hd_v=16, window=0, causal=False, run=32),
+    # latent attention: nope + rope keys wider than the values, own scale
+    "mla": dict(T=256, hd=24, hd_v=16, window=0, causal=True, run=36,
+                scale=0.3),
+}
+
+
+@pytest.mark.parametrize("case", BLOCK_CASES)
+def test_blockwise_attention_matches_naive_and_skips_masked_blocks(
+        case, monkeypatch):
+    """The blockwise form agrees with the naive one, computes only the
+    blocks with a query-key pair the mask keeps, and computes exactly as
+    many as the host's count (`sdpa_blocks`) says."""
+    from repro.models import attention
+    c = BLOCK_CASES[case]
+    B, S, Kv, G, T = 2, 256, 2, 2, c["T"]
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(ks[0], (B, S, Kv, G, c["hd"]))
+    k = jax.random.normal(ks[1], (B, T, Kv, c["hd"]))
+    v = jax.random.normal(ks[2], (B, T, Kv, c["hd_v"]))
+    q_pos = np.arange(S) + c.get("q_offset", 0) if c["causal"] \
+        else np.full(S, T - 1)
+    kv_pos = np.where(np.arange(T) < c.get("kv_valid", T), np.arange(T), -1)
+    kw = dict(window=c["window"], causal=c["causal"], scale=c.get("scale"))
+    monkeypatch.setattr(attention, "BLOCKWISE_THRESHOLD", 64)
+    monkeypatch.setattr(attention, "CHUNK", 32)
+
+    computed = []
+    real_cond = jax.lax.cond
+
+    def counting_cond(pred, taken, skipped, *operands):
+        def counted(*a):
+            jax.debug.callback(lambda: computed.append(1))
+            return taken(*a)
+        return real_cond(pred, counted, skipped, *operands)
+
+    monkeypatch.setattr(jax.lax, "cond", counting_cond)
+    qp, kp = jnp.asarray(q_pos), jnp.asarray(kv_pos)
+    got = attention.sdpa(q, k, v, qp, kp, **kw)
+    jax.effects_barrier()
+    want = attention.naive_sdpa(q, k, v, qp, kp, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    blocks, run = attention.sdpa_blocks(q_pos, kv_pos, window=c["window"],
+                                        causal=c["causal"])
+    assert (blocks, run) == (8 * T // 32, c["run"])
+    assert len(computed) == run
+
+
 def test_mlstm_parallel_matches_recurrent_decode():
     """Chunkwise-parallel train form vs step-by-step decode: same outputs."""
     from repro.models.xlstm import (init_mlstm_cache, mlstm_decode,

@@ -152,16 +152,43 @@ def _deepseek_cell():
                        moe=dataclasses.replace(base.moe, num_experts_held=8))
 
 
-def test_deepseek_prefill_of_a_full_wave_fits_one_chip(one_chip):
-    """A wave of 8 prompts of 7,168 tokens into a cache of 8,192: the
-    prefill's temporaries (one lane's expanded K/V and score blocks, one
-    lane's sorted expert rows) fit beside the 4.8 GB of weights."""
+@pytest.fixture(scope="module")
+def deepseek_prefill(one_chip):
+    """The cell's prefill of a wave of 8 prompts of 7,168 tokens into a
+    cache of 8,192, compiled."""
     model = build_model(_deepseek_cell())
     params = _on(one_chip, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     eng = ServingEngine(model, params, max_seq=8192)
     tokens = jax.ShapeDtypeStruct((8, 7168), jnp.int32, sharding=one_chip)
-    compiled = eng._prefill.lower(params, {"tokens": tokens}).compile()
-    assert _device_bytes(compiled) < 10e9 < HBM_BYTES
+    return eng._prefill.lower(params, {"tokens": tokens}).compile()
+
+
+def test_deepseek_prefill_of_a_full_wave_fits_one_chip(deepseek_prefill):
+    """The prefill's temporaries (one lane's expanded K/V and score
+    blocks, one lane's sorted expert rows) fit beside the 4.8 GB of
+    weights."""
+    assert _device_bytes(deepseek_prefill) < 10e9 < HBM_BYTES
+
+
+def test_deepseek_prefill_skips_masked_blocks_inside_the_kv_loop(
+        deepseek_prefill):
+    """Each latent-attention layer program (the dense first layer, the
+    scanned group) computes a block under a conditional inside its KV
+    loop, whose result is the running max, sum and float32 accumulator of
+    a chunk of 1,024 queries over 128 heads; the accumulator is not
+    copied besides."""
+    lines = deepseek_prefill.as_text().splitlines()
+    acc = "f32[1,128,1,1024,128]"
+    conds = [ln for ln in lines if " conditional(" in ln]
+    assert len(conds) == 2
+    for ln in conds:
+        op = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert acc in ln.split(" conditional(")[0] and "/mla/" in op
+        assert op.endswith("/while/body/closed_call/cond")
+        assert op.count("while/body") >= 3      # lanes, query chunks, KV
+    copies = [ln.split(" copy")[0] for ln in lines
+              if re.search(r" copy(-start)?\(", ln)]
+    assert copies and not [c for c in copies if acc in c]
 
 
 def test_deepseek_decode_updates_latent_cache_in_place(one_chip):
